@@ -23,17 +23,19 @@ def verify_intensity(block_m: int, block_n: int = 2048, b: int = 4,
     ``sparse_verify_batch_pallas``.
 
     Bytes: the (b, W, BLOCK_N) db block is loaded ONCE per cell and
-    amortized over BLOCK_M queries; the query tile, base-distance plane,
-    and the two output planes scale with BLOCK_M.  Ops per (query, lane):
-    b XORs + (b-1) ORs over W words, W popcounts, (W-1)+1 adds (word sum
-    + base add), 1 compare, 1 min.  At BLOCK_M=1 this is the original
-    ~1.5 int-ops/byte memory-bound scan; intensity grows ~linearly with
-    BLOCK_M until the per-query planes dominate the byte count."""
+    amortized over BLOCK_M queries; the query tile, the base-distance
+    plane and the one output (distance) plane scale with BLOCK_M.  Ops
+    per (query, lane): b XORs + (b-1) ORs over W words, W popcounts,
+    (W-1)+1 adds (word sum + base add), 1 min (the survival compare runs
+    outside the kernel, fused into its consumer).  At BLOCK_M=1 this is
+    the original ~1.5 int-ops/byte memory-bound scan; intensity grows
+    ~linearly with BLOCK_M until the per-query planes dominate the byte
+    count."""
     db_bytes = b * W * block_n * 4
     q_bytes = b * W * block_m * 4
-    plane_bytes = block_m * block_n * 4          # base in, mask out, dist out
-    bytes_total = db_bytes + q_bytes + 3 * plane_bytes
-    ops_per_pair = (b * W) + (b - 1) * W + W + W + 2
+    plane_bytes = block_m * block_n * 4          # base in, dist out
+    bytes_total = db_bytes + q_bytes + 2 * plane_bytes
+    ops_per_pair = (b * W) + (b - 1) * W + W + W + 1
     ops_total = block_m * block_n * ops_per_pair
     return {"ops": float(ops_total), "bytes": float(bytes_total),
             "intensity": ops_total / bytes_total,
@@ -109,7 +111,7 @@ def run(csv=None) -> None:
                     f"gain_vs_bm1={r['intensity'] / base:.2f}x;"
                     f"db_streams=m/{bm}")
         # intensity must grow with the query tile — the why of the kernel
-        # (saturates near ops/12-bytes once the per-query base/mask/dist
+        # (saturates near ops/8-bytes once the per-query base/dist
         # planes dominate; the db-stream term keeps falling as m/BLOCK_M)
         assert (verify_intensity(8)["intensity"]
                 > 1.8 * verify_intensity(1)["intensity"])

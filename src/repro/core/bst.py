@@ -6,7 +6,12 @@ operation
     children(parent_ids: int32[F]) -> (ids, labels, exists): int32[F, 2^b]
 
 — i.e. the paper's ``children(u)`` but over a whole frontier at once
-(see DESIGN.md §2: DFS -> level-synchronous traversal).  Encodings:
+(see DESIGN.md §2: DFS -> level-synchronous traversal).  The encodings
+gather with (2^b, F) index arrays and transpose the results: the TPU
+compiler emits a gather whose index array has a short minor axis (2^b)
+as straight-line code that grows with F — minutes of compile time per
+program at serving frontier widths — and a long minor axis as a loop.
+Encodings:
 
   * ``DenseLevel``  — complete 2^b-ary level: children are arithmetic,
                       storage is *zero bits* (paper §V-A).
@@ -90,12 +95,12 @@ class TableLevel:
 
     def children(self, u: jnp.ndarray):
         A = 1 << self.b
-        c = jnp.arange(A, dtype=jnp.int32)[None, :]
+        c = jnp.arange(A, dtype=jnp.int32)[:, None]
         u_safe = jnp.clip(u, 0, self.t_prev - 1)
-        pos = u_safe[:, None] * A + c                    # (F, A)
-        exists = self.H.get(pos) == 1
-        ids = self.H.rank(pos)                           # ones before pos = child index
-        labels = jnp.broadcast_to(c, ids.shape)
+        pos = u_safe[None, :] * A + c                    # (A, F)
+        exists = (self.H.get(pos) == 1).T
+        ids = self.H.rank(pos).T                         # ones before pos = child index
+        labels = jnp.broadcast_to(c.T, ids.shape)
         return ids, labels, exists
 
     def model_bits(self) -> int:
@@ -127,11 +132,11 @@ class ListLevel:
         u_safe = jnp.clip(u, 0, self.t_prev - 1)
         start = self.B.select(u_safe + 1)                # (F,)
         end = self.B.select(u_safe + 2)                  # t for the last parent
-        j = jnp.arange(A, dtype=jnp.int32)[None, :]
-        ids = start[:, None] + j
-        exists = ids < end[:, None]
+        j = jnp.arange(A, dtype=jnp.int32)[:, None]
+        ids = start[None, :] + j                         # (A, F)
+        exists = ids < end[None, :]
         labels = self.C[jnp.clip(ids, 0, t - 1)].astype(jnp.int32)
-        return ids, labels, exists
+        return ids.T, labels.T, exists.T
 
     def model_bits(self) -> int:
         t = int(self.C.shape[0])
@@ -164,11 +169,11 @@ class LoudsLevel:
         s0 = self.U.select0(jnp.maximum(u_safe, 1))
         start = jnp.where(u_safe == 0, 0, s0 - u_safe + 1)
         end = self.U.select0(u_safe + 1) - u_safe
-        j = jnp.arange(A, dtype=jnp.int32)[None, :]
-        ids = start[:, None] + j
-        exists = ids < end[:, None]
+        j = jnp.arange(A, dtype=jnp.int32)[:, None]
+        ids = start[None, :] + j                         # (A, F)
+        exists = ids < end[None, :]
         labels = self.C[jnp.clip(ids, 0, t - 1)].astype(jnp.int32)
-        return ids, labels, exists
+        return ids.T, labels.T, exists.T
 
     def model_bits(self) -> int:
         t = int(self.C.shape[0])

@@ -148,7 +148,7 @@ class _Group(NamedTuple):
 
     geom: SuffixGeometry
     cols_hot: Optional[jnp.ndarray]   # concatenated hot columns (device)
-    base_idx: jnp.ndarray             # (n_group,) int32 device constant
+    base_idx: jnp.ndarray             # (n_group,) int32 device lane
     perm: np.ndarray                  # (n_group,) int64 stack positions
     cold_blocks: Tuple[int, ...]      # indexes into store.blocks
     cold_bytes: int
@@ -184,6 +184,8 @@ class ColumnStore:
         self.gen = 0                   # bumped on every tier flip
         self._tick = 0                 # LRU clock
         self._plan: Optional[Tuple[_Group, ...]] = None
+        self._order: Tuple[Optional[jnp.ndarray], Optional[jnp.ndarray]] = (
+            None, None)
 
     @property
     def n_cols(self) -> int:
@@ -322,7 +324,23 @@ class ColumnStore:
                 pay_cold_bytes=sum(self.blocks[i].pay_bytes
                                    for i in cold)))
         self._plan = tuple(groups)
+        order = np.concatenate([g.perm for g in groups]) if groups else \
+            np.zeros((0,), np.int64)
+        if np.array_equal(order, np.arange(self.n_cols)):
+            self._order = (None, None)
+        else:
+            self._order = (jnp.asarray(order.astype(np.int32)),
+                           jnp.asarray(np.argsort(order).astype(np.int32)))
         return self._plan
+
+    def order(self) -> Tuple[Optional[jnp.ndarray], Optional[jnp.ndarray]]:
+        """Device int32 permutations between the plan's group-major column
+        order (groups concatenated, each in its ``perm`` order) and global
+        stack order: ``(order, inverse)`` with ``order[j]`` the stack
+        position of group-major column j.  ``(None, None)`` when the two
+        orders coincide (one group, or groups already in stack order)."""
+        self.plan()
+        return self._order
 
     def stage(self) -> Tuple[Optional[jnp.ndarray], ...]:
         """Copy-ahead: upload every cold block's columns into one device

@@ -107,22 +107,12 @@ def _compact(ids: jnp.ndarray, dists: jnp.ndarray, valid: jnp.ndarray,
 def _compact_batch(ids: jnp.ndarray, dists: jnp.ndarray, valid: jnp.ndarray,
                    capacity: int):
     """Row-wise stable masked compaction: (m, K) candidates -> (m,
-    capacity) frontier.  Each query compacts independently (per-row
-    cumsum + one 2D scatter); overflow is counted per query."""
-    m = ids.shape[0]
-    total = valid.sum(axis=1, dtype=jnp.int32)            # (m,)
-    pos = jnp.cumsum(valid, axis=1) - 1                   # (m, K)
-    slot = jnp.where(valid & (pos < capacity), pos, capacity)
-    row = jnp.arange(m, dtype=jnp.int32)[:, None]
-    out_ids = jnp.zeros((m, capacity + 1), jnp.int32).at[row, slot].set(
-        ids, mode="drop")
-    out_dists = jnp.full((m, capacity + 1), BIG, jnp.int32).at[row, slot].set(
-        dists, mode="drop")
-    kept = jnp.minimum(total, capacity)
-    out_valid = jnp.arange(capacity + 1, dtype=jnp.int32)[None, :] < kept[:, None]
-    overflow = jnp.maximum(total - capacity, 0)
-    return (out_ids[:, :capacity], out_dists[:, :capacity],
-            out_valid[:, :capacity], overflow)
+    capacity) frontier.  Each query compacts independently — ``_compact``
+    once per row (``lax.map``): the TPU compiler emits one 2-D (row,
+    slot) scatter as code that grows with m and the frontier width, a
+    1-D scatter in a loop as one body.  Overflow is counted per query."""
+    return jax.lax.map(lambda row: _compact(*row, capacity),
+                       (ids, dists, valid))
 
 
 def _leaf_live(index: SketchIndex, id_live: jnp.ndarray) -> jnp.ndarray:
@@ -257,11 +247,15 @@ def scatter_root_plane(ids: jnp.ndarray, vals: jnp.ndarray,
     suffix verify adds to complete the full-length Hamming distance bit
     for bit.  The scratch slot ``t_root`` absorbs ``mode="drop"`` pads
     and is sliced off."""
-    row = jnp.arange(m, dtype=jnp.int32)[:, None]
-    safe = jnp.where(valid, ids, 0)
-    reach = jnp.full((m, t_root + 1), BIG, jnp.int32).at[
-        row, safe].min(jnp.where(valid, vals, BIG), mode="drop")
-    return reach[:, :t_root]
+    def one_row(row):
+        ids_r, vals_r, valid_r = row
+        return jnp.full((t_root + 1,), BIG, jnp.int32).at[
+            jnp.where(valid_r, ids_r, t_root)].min(
+                jnp.where(valid_r, vals_r, BIG), mode="drop")[:t_root]
+    # one 1-D scatter per query row: the TPU compiler emits a 2-D
+    # (row, id) scatter as straight-line code that grows with the
+    # frontier width (see ``bst`` on index layouts)
+    return jax.lax.map(one_row, (ids, vals, valid))
 
 
 def select_topk_columns(dist: jnp.ndarray, col_ids: jnp.ndarray, k: int):
@@ -271,22 +265,41 @@ def select_topk_columns(dist: jnp.ndarray, col_ids: jnp.ndarray, k: int):
     dist: (m, R) int32 — one distance per (query, column), BIG on
     non-results; col_ids: (R,) int32 global labels per column; returns
     ((m, k) int32 ids, (m, k) int32 dists), each row ascending by
-    (distance, label) — an exact lexicographic two-key sort
-    (``lax.sort`` with ``num_keys=2``), so tie order matches the host
-    selection bit for bit; BIG lanes come back as (-1, BIG) pads.
-    Requires k <= R (the caller clamps k to the column count)."""
+    (distance, label), so tie order matches the host selection bit for
+    bit; BIG lanes come back as (-1, BIG) pads.
+    Requires k <= R (the caller clamps k to the column count).
+
+    Two lowerings, identical bits: small k runs ``k`` unrolled min /
+    tie-break-min reduction passes, large k one lexicographic
+    ``lax.sort`` with ``num_keys=2``.  The TPU compiler spends tens of
+    seconds on a full-plane sort at R in the millions, the reduction
+    passes compile in about two."""
     m, R = dist.shape
     labels = jnp.broadcast_to(col_ids.astype(jnp.int32)[None, :], (m, R))
-    d_sorted, l_sorted = jax.lax.sort((dist, labels), dimension=-1,
-                                      num_keys=2)
-    d_k, l_k = d_sorted[:, :k], l_sorted[:, :k]
+    if k <= _ITER_SELECT_MAX_K:
+        d = dist.astype(jnp.int32)
+        picks = []
+        for _ in range(k):
+            mn = d.min(-1, keepdims=True)
+            tie = d == mn
+            lab = jnp.where(tie, labels,
+                            jnp.int32(2 ** 31 - 1)).min(-1, keepdims=True)
+            picks.append((mn[:, 0], lab[:, 0]))
+            # picked lanes rise past BIG, so BIG lanes still read as pads
+            d = jnp.where(tie & (labels == lab), jnp.int32(BIG + 1), d)
+        d_k = jnp.stack([p[0] for p in picks], -1)
+        l_k = jnp.stack([p[1] for p in picks], -1)
+    else:
+        d_sorted, l_sorted = jax.lax.sort((dist, labels), dimension=-1,
+                                          num_keys=2)
+        d_k, l_k = d_sorted[:, :k], l_sorted[:, :k]
     return jnp.where(d_k < BIG, l_k, -1), jnp.minimum(d_k, BIG)
 
 
-# crossover between the unrolled reduction selection and the full sort:
-# each reduction pick costs ~6 plane traversals, the 4-operand sort
-# costs ~90 picks' worth on CPU — stay iterative through every
-# serving-sized k
+# crossover between the unrolled reduction selections and the full
+# sort: each reduction pick costs a few plane traversals, the sort ~90
+# picks' worth on CPU and a far longer TPU compile — stay iterative
+# through every serving-sized k
 _ITER_SELECT_MAX_K = 32
 
 
@@ -360,14 +373,11 @@ def _search_trace_batch(index: SketchIndex, qs: jnp.ndarray, *, tau: int,
     ids, dists, valid, overflow, traversed = _traverse_frontier_batch(
         index, qs, tau=tau, caps=caps)
 
-    row = jnp.arange(m, dtype=jnp.int32)[:, None]
-    safe_ids = jnp.where(valid, ids, 0)
     if index.tail is not None:
         tail = index.tail
         # batched scatter of frontier distances onto per-query ℓ_s root
         # planes (+∞ = pruned subtrie)
-        base_root = jnp.full((m, tail.t_root), BIG, jnp.int32).at[
-            row, safe_ids].min(jnp.where(valid, dists, BIG), mode="drop")
+        base_root = scatter_root_plane(ids, dists, valid, m, tail.t_root)
         base_leaf = base_root[:, tail.leaf_root]                  # (m, t_L)
         if tail.suffix_len > 0:
             q_sfx = pack_vertical_jax(qs[:, index.ls:], index.b)  # (m, b, W)
@@ -383,9 +393,8 @@ def _search_trace_batch(index: SketchIndex, qs: jnp.ndarray, *, tau: int,
             leaf_dist = base_leaf
     else:
         # no collapsed tail (LOUDS/FST baselines): frontier is at level L
-        t_L = index.t[index.L]
-        leaf_dist = jnp.full((m, t_L), BIG, jnp.int32).at[row, safe_ids].min(
-            jnp.where(valid, dists, BIG), mode="drop")
+        leaf_dist = scatter_root_plane(ids, dists, valid, m,
+                                       index.t[index.L])
         if live is not None:
             leaf_dist = jnp.where(live[None, :], leaf_dist, BIG)
         survive = leaf_dist <= tau

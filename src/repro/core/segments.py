@@ -307,8 +307,8 @@ _SHARDED_SEARCHER_CACHE_CAP = 64
 # Fused one-dispatch arena programs, keyed on (index instance,
 # segment-serial fingerprint, kind, τ, capacity rung, k, block_m) —
 # serials are monotonic, so a rebuilt stack can never alias a stale
-# program; the closures pin the segment indexes and arena arrays they
-# stream, and an index drops its own dead-generation entries the moment
+# program; each entry pins the segment indexes and arena arrays it
+# streams, and an index drops its own dead-generation entries the moment
 # its fingerprint changes (``_fused_fn``).
 _FUSED_CACHE: Dict[tuple, object] = {}
 _FUSED_CACHE_CAP = 32
@@ -317,6 +317,29 @@ _FUSED_CACHE_CAP = 32
 def clear_fused_cache() -> None:
     """Drop every compiled fused arena program (and its pinned arrays)."""
     _FUSED_CACHE.clear()
+
+
+class _BoundProgram:
+    """A jitted fused program plus the device-resident corpus it reads
+    (trie levels, hot columns, lanes, permutations), passed as the
+    program's first argument on every call.  A device array the traced
+    function closes over becomes an HLO constant — a copy of the corpus
+    inside every compiled program, paid in compile time and device
+    memory; an argument is only a buffer reference."""
+
+    def __init__(self, fn, corpus):
+        self.jit = jax.jit(fn)
+        self.corpus = corpus
+
+    def __call__(self, *args):
+        return self.jit(self.corpus, *args)
+
+
+def _take_columns(plane: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """plane[:, idx], one 1-D gather per row (``lax.map``): a single
+    column gather lays its slices out row-minor and pads the short row
+    axis to 128 lanes on TPU (see ``hamming_kernel._gather_base``)."""
+    return jax.lax.map(lambda row: row[idx], plane)
 
 
 def _ladder_topk(columns_fn, n_live: int, b: int, L: int, qs: np.ndarray,
@@ -1507,7 +1530,7 @@ class SegmentedIndex:
         serials = self._seg_serials()
         suffix_store = self.backend == "bst" and self.layout == "suffix"
         # the placement generation joins the fingerprint: a tier flip
-        # moves columns between device closure and staged slab, so a
+        # moves columns between the hot corpus and the staged slabs, so a
         # pre-flip program must never be reused
         gen = self._refresh_store().gen if suffix_store else 0
         if (serials, gen) != self._fused_stamp:
@@ -1601,83 +1624,96 @@ class SegmentedIndex:
         carries the traversal's exact *prefix distances* (not 0/BIG) and
         the verify runs over per-geometry suffix column groups, so
         prefix + suffix reproduces the full-length Hamming distance bit
-        for bit.  Hot groups close over device columns; cold columns
-        arrive through the staged slabs (traced args, uploaded by
-        ``ColumnStore.stage`` before the rung loop).  Multiple geometry
-        groups mean multiple verify kernel bodies INSIDE the one
-        program — still one fused dispatch per rung."""
+        for bit.  The corpus — trie levels, hot group columns, base
+        lanes, gids, the group-order permutations — is the program's
+        first argument (``_BoundProgram``); cold columns arrive through
+        the staged slabs (uploaded by ``ColumnStore.stage`` before the
+        rung loop).  Multiple geometry groups mean multiple verify
+        kernel bodies INSIDE the one program — still one fused dispatch
+        per rung."""
         store = self._refresh_store()
         plan = store.plan()
+        order, inv = store.order()
         cap = CAP_MAX_DEFAULT << rung
         indexes = [seg.index for seg in self.segments]
         caps_list = [frontier_capacities(ix.t, self.b, tau, cap)
                      for ix in indexes]
         t_roots = [int(ix.tail.t_root) for ix in indexes]
-        gids0 = store.gids
-        r_sealed = store.n_cols
+        geoms = [g.geom for g in plan]
+        spans = np.cumsum([0] + [len(g.perm) for g in plan])
         b_, L, block_m = self.b, self.L, self.block_m
+        corpus = {"indexes": indexes,
+                  "cols": [g.cols_hot for g in plan],
+                  "base_idx": [g.base_idx for g in plan],
+                  "gids": store.gids, "order": order, "inv": inv}
 
-        @jax.jit
-        def run(qs, live_sealed, staged, delta_vert, delta_live,
+        def run(corpus, qs, live_sealed, staged, delta_vert, delta_live,
                 delta_gids):
             _note_trace()
             qsi = qs.astype(jnp.int32)
             m = qsi.shape[0]
             planes = [jnp.zeros((m, 1), jnp.int32)]  # slot 0: delta base
             overflow = jnp.zeros((m,), jnp.int32)
-            for ix, caps, t_root in zip(indexes, caps_list, t_roots):
+            for ix, caps, t_root in zip(corpus["indexes"], caps_list,
+                                        t_roots):
                 ids, dists, valid, ov, _ = _traverse_frontier_batch(
                     ix, qsi, tau=tau, caps=caps)
                 planes.append(scatter_root_plane(
                     ids, dists, valid, m, t_root))
                 overflow = overflow + ov
             base_plane = jnp.concatenate(planes, axis=1)
+            order, inv = corpus["order"], corpus["inv"]
             dist_parts: List[jnp.ndarray] = []
-            order_parts: List[np.ndarray] = []
-            for g, slab in zip(plan, staged):
-                axis = 0 if g.geom.packed else -1
-                parts = [p for p in (g.cols_hot, slab) if p is not None]
+            for gi, (geom, cols_hot, base_idx, slab) in enumerate(zip(
+                    geoms, corpus["cols"], corpus["base_idx"], staged)):
+                axis = 0 if geom.packed else -1
+                parts = [p for p in (cols_hot, slab) if p is not None]
                 cols_g = (parts[0] if len(parts) == 1
                           else jnp.concatenate(parts, axis=axis))
-                live_g = live_sealed[g.perm]
-                S = g.geom.suffix_len
-                if g.geom.packed:
+                cols = slice(spans[gi], spans[gi + 1])
+                live_g = live_sealed[cols if order is None else order[cols]]
+                S = geom.suffix_len
+                if geom.packed:
                     qw = pack_suffix_words_jax(qsi[:, L - S:], b_)
                     hm, d = ops.sparse_verify_arena_packed(
-                        cols_g, qw, base_plane, g.base_idx, live_g, b=b_,
+                        cols_g, qw, base_plane, base_idx, live_g, b=b_,
                         S=S, tau=tau, block_m=block_m)
                 else:
                     qv = jnp.transpose(
                         pack_vertical_jax(qsi[:, L - S:], b_), (1, 2, 0))
                     hm, d = ops.sparse_verify_arena(
-                        cols_g, qv, base_plane, g.base_idx, live_g,
+                        cols_g, qv, base_plane, base_idx, live_g,
                         tau=tau, block_m=block_m)
                 dist_parts.append(jnp.where(hm > 0, d, BIG))
-                order_parts.append(g.perm)
+            dist_sealed = (jnp.concatenate(dist_parts, axis=1) if dist_parts
+                           else jnp.zeros((m, 0), jnp.int32))
             # the delta buffer scans full-length (its rows have no trie,
             # hence no ℓ_s to slice at) — same arithmetic as the full
             # arena's trivial base slot 0
             q_vert = jnp.transpose(pack_vertical_jax(qsi, b_), (1, 2, 0))
             dd = ops.hamming_distances(delta_vert, q_vert)
             dd = jnp.where(delta_live[None, :] & (dd <= tau), dd, BIG)
-            dist_parts.append(dd.astype(jnp.int32))
-            ndb = delta_vert.shape[-1]
-            order_parts.append(np.arange(r_sealed, r_sealed + ndb))
-            # restore global stack order with a static inverse
-            # permutation (ndb is trace-static), so the column contract
-            # and tie order match the full-length arena exactly
-            inv = np.argsort(np.concatenate(order_parts))
-            dist = jnp.concatenate(dist_parts, axis=1)[:, inv]
+            dd = dd.astype(jnp.int32)
+            if kind == "topk":
+                # selection sorts on (distance, id), so it runs on the
+                # group-major columns with the labels permuted instead
+                gids = (corpus["gids"] if order is None
+                        else corpus["gids"][order])
+                dist = jnp.concatenate([dist_sealed, dd], axis=1)
+                sel_ids, sel_d = select_topk_columns(
+                    dist, jnp.concatenate([gids, delta_gids]), kk)
+                min_surv = (dist < BIG).sum(axis=1).min()
+                return sel_ids, sel_d, min_surv, overflow.sum()
+            # restore global stack order, so the column contract and the
+            # re-rank stage match the full-length arena exactly
+            if inv is not None:
+                dist_sealed = _take_columns(dist_sealed, inv)
+            dist = jnp.concatenate([dist_sealed, dd], axis=1)
             if kind == "cols":
                 return dist, overflow.sum()
-            if kind == "dist":
-                return (dist, (dist < BIG).sum(axis=1).min(),
-                        overflow.sum())
-            sel_ids, sel_d = select_topk_columns(
-                dist, jnp.concatenate([gids0, delta_gids]), kk)
-            min_surv = (dist < BIG).sum(axis=1).min()
-            return sel_ids, sel_d, min_surv, overflow.sum()
-        return run
+            return (dist, (dist < BIG).sum(axis=1).min(),
+                    overflow.sum())
+        return _BoundProgram(run, corpus)
 
     def _build_fused_multi(self, kind: str, tau: int, rung: int,
                            kk: Optional[int]):
@@ -1944,41 +1980,46 @@ class SegmentedIndex:
 
     def _build_rerank(self, metric: str, kk: int):
         """ONE jitted stage-2 program: assemble the (Wp, R) payload plane
-        in global column order (hot groups close over device bitmaps,
-        cold arrive through the staged payload slabs, delta through its
+        in global column order (hot groups from the corpus argument,
+        cold through the staged payload slabs, delta through its
         bucketed plane), score the stage-1 survivors with the exact
         re-rank kernel, and select the k best (score desc, id asc) on
-        device — the dist plane never leaves the device between stages."""
+        device — the dist plane never leaves the device between stages.
+        Payload bitmaps and gids are the program's first argument
+        (``_BoundProgram``), never closed over."""
         block_m = self.block_m
+
+        def score_select(pays, gids, dist, q_pay, delta_pay, delta_gids):
+            pays = jnp.concatenate([pays, delta_pay], axis=-1)
+            surv = (dist < BIG).astype(jnp.int32)
+            scores = ops.exact_rerank(pays, q_pay, surv, metric=metric,
+                                      block_m=block_m)
+            col_ids = jnp.concatenate([gids, delta_gids])
+            return select_topk_scores(scores, dist, col_ids, kk)
+
         if self.backend == "bst" and self.layout == "suffix":
             store = self._refresh_store()
             plan = store.plan()
-            gids0 = store.gids
-            r_sealed = store.n_cols
+            corpus = {"pays": [g.pays_hot for g in plan],
+                      "gids": store.gids, "inv": store.order()[1]}
 
-            @jax.jit
-            def run(dist, q_pay, staged_pays, delta_pay, delta_gids):
+            def run(corpus, dist, q_pay, staged_pays, delta_pay,
+                    delta_gids):
                 _note_trace()
                 pay_parts: List[jnp.ndarray] = []
-                order_parts: List[np.ndarray] = []
-                for g, slab in zip(plan, staged_pays):
-                    parts = [p for p in (g.pays_hot, slab) if p is not None]
+                for pays_hot, slab in zip(corpus["pays"], staged_pays):
+                    parts = [p for p in (pays_hot, slab) if p is not None]
                     pay_parts.append(parts[0] if len(parts) == 1
                                      else jnp.concatenate(parts, axis=-1))
-                    order_parts.append(g.perm)
-                ndb = delta_pay.shape[-1]
-                pay_parts.append(delta_pay)
-                order_parts.append(np.arange(r_sealed, r_sealed + ndb))
-                # the same trace-static inverse permutation the dist
-                # program applied — pay columns land in dist order
-                inv = np.argsort(np.concatenate(order_parts))
-                pays = jnp.concatenate(pay_parts, axis=-1)[:, inv]
-                surv = (dist < BIG).astype(jnp.int32)
-                scores = ops.exact_rerank(pays, q_pay, surv, metric=metric,
-                                          block_m=block_m)
-                col_ids = jnp.concatenate([gids0, delta_gids])
-                return select_topk_scores(scores, dist, col_ids, kk)
-            return run
+                pays = (jnp.concatenate(pay_parts, axis=-1) if pay_parts
+                        else delta_pay[:, :0])
+                # the same group-major -> stack-order permutation the
+                # dist program applied: pay columns land in dist order
+                if corpus["inv"] is not None:
+                    pays = _take_columns(pays, corpus["inv"])
+                return score_select(pays, corpus["gids"], dist, q_pay,
+                                    delta_pay, delta_gids)
+            return _BoundProgram(run, corpus)
 
         # non-suffix configurations: sealed payloads live in the
         # incremental device payload arena, already in stack order
@@ -1994,16 +2035,11 @@ class SegmentedIndex:
         else:
             gids0 = jnp.zeros((0,), jnp.int32)
 
-        @jax.jit
-        def run(dist, q_pay, delta_pay, delta_gids):
+        def run(corpus, dist, q_pay, delta_pay, delta_gids):
             _note_trace()
-            pays = jnp.concatenate([pays0, delta_pay], axis=-1)
-            surv = (dist < BIG).astype(jnp.int32)
-            scores = ops.exact_rerank(pays, q_pay, surv, metric=metric,
-                                      block_m=block_m)
-            col_ids = jnp.concatenate([gids0, delta_gids])
-            return select_topk_scores(scores, dist, col_ids, kk)
-        return run
+            return score_select(corpus["pays"], corpus["gids"], dist, q_pay,
+                                delta_pay, delta_gids)
+        return _BoundProgram(run, {"pays": pays0, "gids": gids0})
 
     def _fused_topk_rerank(self, qs: np.ndarray, k: int,
                            tau0: Optional[int], metric: str,

@@ -13,21 +13,33 @@ vectorizes the whole XOR/OR/popcount chain across 128-sketch lanes with
 the (tiny) b·W plane/word axes on sublanes.
 
 Query tiling (the batched-serving optimisation): a grid cell loads one
-(b, W, BLOCK_N) database block ONCE and plays a whole (b, W, BLOCK_M)
-query tile against it, emitting (BLOCK_M, BLOCK_N) output planes.  HBM
-traffic for the database drops from m streams (one per query, the naive
-vmap) to ⌈m/BLOCK_M⌉ streams, and the arithmetic intensity of the scan
-scales ~linearly with BLOCK_M until the (BLOCK_M, BLOCK_N) output planes
+(b, W, BLOCK_N) database block ONCE and plays a whole BLOCK_M-query tile
+against it, emitting (BLOCK_M, BLOCK_N) output planes.  HBM traffic for
+the database drops from m streams (one per query, the naive vmap) to
+⌈m/BLOCK_M⌉ streams, and the arithmetic intensity of the scan scales
+~linearly with BLOCK_M until the (BLOCK_M, BLOCK_N) output planes
 dominate the byte count (see benchmarks/roofline.py).
+
+Queries sit on **sublanes**: the ``*_pallas`` entry points take the
+callers' (b, W, m) planes and hand the kernel (m, b·W) query rows, so a
+query block is (BLOCK_M, b·W) — its last dimension is the whole axis,
+which Mosaic accepts at any width, and BLOCK_M = 8 fills one sublane
+tile.  Inside the kernel one (BLOCK_M, 1) query column broadcasts along
+lanes against one (1, BLOCK_N) database row.
 
 Block-shape reasoning (v5e: 128 lanes, 8 sublanes, ~16 MiB VMEM/core):
   * BLOCK_N multiple of 128 (lane width).  Default 2048.
-  * BLOCK_M on sublanes of the output tile; default 8 (one sublane
-    register's worth) — the XOR intermediate is (b, W, BLOCK_M, BLOCK_N)
-    = at most 16·8·2048·4 = 1 MiB of VMEM, leaving room to double-buffer.
+  * BLOCK_M multiple of 8, or the whole (padded) query axis when m < 8;
+    default 8 — the per-word XOR intermediate is (BLOCK_M, BLOCK_N) =
+    8·2048·4 = 64 KiB of VMEM, leaving room to double-buffer.
   * b·W ≤ 16 for every paper dataset (b=2,W=1 … b=8,W=2); at BLOCK_M=1
     the kernel degenerates to the original memory-bound single-query
     scan at ~1.5 int-ops per byte.
+
+Every verify kernel emits one (m, n) int32 plane — the exact total
+distance, clamped to BIG on pruned or dead lanes; the survival mask is
+``dist <= tau`` (τ < BIG), derived outside the kernel so XLA fuses it
+into its consumer instead of writing a second plane to HBM.
 """
 
 from __future__ import annotations
@@ -45,25 +57,52 @@ DEFAULT_BLOCK_M = 8
 # not import core); verified equal in tests/test_kernels.py.
 BIG = 1 << 20
 
+# VMEM the re-rank kernel's double-buffered blocks may claim: v5e's
+# default scoped VMEM limit is 16 MiB per core, and the kernel body's
+# (BLOCK_M, BLOCK_N) intermediates need the rest.
+RERANK_VMEM_BUDGET = 8 << 20
 
-def _tile_distances(db, q, *, b: int, W: int):
-    """(b, W, BLOCK_N) uint32 x (b, W, BLOCK_M) uint32 ->
-    (BLOCK_M, BLOCK_N) int32 Hamming distances; b and W are python
-    constants so both reductions fully unroll."""
-    diff = db[:, :, None, :] ^ q[:, :, :, None]   # (b, W, BLOCK_M, BLOCK_N)
-    acc = diff[0]
-    for i in range(1, b):
-        acc = acc | diff[i]
-    pops = jax.lax.population_count(acc).astype(jnp.int32)  # (W, M, N)
-    dist = pops[0]
-    for w in range(1, W):
-        dist = dist + pops[w]
+
+def _query_rows(q_vert: jnp.ndarray) -> jnp.ndarray:
+    """(b, W, m) query planes -> (m, b·W) query rows (column i·W + w is
+    plane i, word w): the sublane-major query layout of every kernel."""
+    b, W, m = q_vert.shape
+    return jnp.transpose(q_vert, (2, 0, 1)).reshape(m, b * W)
+
+
+def _tile_distances(db_ref, q_ref, *, b: int, W: int):
+    """(b, W, BLOCK_N) uint32 database block x (BLOCK_M, b·W) uint32
+    query rows -> (BLOCK_M, BLOCK_N) int32 Hamming distances: per word,
+    OR the b plane XORs, popcount, and sum the words.  b and W are
+    python constants so both reductions fully unroll."""
+    dist = None
+    for w in range(W):
+        acc = None
+        for i in range(b):
+            x = db_ref[i, w:w + 1, :] ^ q_ref[:, i * W + w:i * W + w + 1]
+            acc = x if acc is None else acc | x
+        pops = jax.lax.population_count(acc).astype(jnp.int32)
+        dist = pops if dist is None else dist + pops
     return dist
 
 
 def _hamming_kernel(db_ref, q_ref, out_ref, *, b: int, W: int):
     """One (query tile j, db block i) cell: (BLOCK_M, BLOCK_N) distances."""
-    out_ref[...] = _tile_distances(db_ref[...], q_ref[...], b=b, W=W)
+    out_ref[...] = _tile_distances(db_ref, q_ref, b=b, W=W)
+
+
+def _scan_specs(db_shape, q_width: int, block_m: int, block_n: int):
+    """BlockSpecs shared by the query-tiled scans: the database streams
+    (..., BLOCK_N) lane blocks along grid axis 1, the query tile is the
+    (BLOCK_M, q_width) row block of grid axis 0."""
+    lead = (0,) * (len(db_shape) - 1)
+    return [pl.BlockSpec(tuple(db_shape[:-1]) + (block_n,),
+                         lambda j, i: lead + (i,)),
+            pl.BlockSpec((block_m, q_width), lambda j, i: (j, 0))]
+
+
+def _tile_spec(block_m: int, block_n: int):
+    return pl.BlockSpec((block_m, block_n), lambda j, i: (j, i))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
@@ -74,39 +113,80 @@ def hamming_distances_pallas(db_vert: jnp.ndarray, q_vert: jnp.ndarray,
     """(b, W, n) x (b, W, m) -> (m, n) int32 distances via pallas_call.
 
     Grid is (m/block_m, n/block_n): query tiles on the outer axis so each
-    tile's planes stay VMEM-resident while database blocks stream past —
-    the database is read ⌈m/block_m⌉ times total.  ``n`` must be a
-    multiple of ``block_n`` and ``m`` of ``block_m`` (ops.py pads both).
+    tile stays VMEM-resident while database blocks stream past — the
+    database is read ⌈m/block_m⌉ times total.  ``n`` must be a multiple
+    of ``block_n`` and ``m`` of ``block_m`` (ops.py pads both).
     """
     b, W, n = db_vert.shape
     m = q_vert.shape[-1]
     assert n % block_n == 0, (n, block_n)
     assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, n // block_n)
     kernel = functools.partial(_hamming_kernel, b=b, W=W)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, W, block_n), lambda j, i: (0, 0, i)),
-            pl.BlockSpec((b, W, block_m), lambda j, i: (0, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
+        grid=(m // block_m, n // block_n),
+        in_specs=_scan_specs(db_vert.shape, b * W, block_m, block_n),
+        out_specs=_tile_spec(block_m, block_n),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(db_vert, q_vert)
+    )(db_vert, _query_rows(q_vert))
 
 
-def _verify_batch_kernel(db_ref, q_ref, base_ref, mask_ref, dist_ref,
-                         *, b: int, W: int, tau: int):
-    """Fused query-tiled sparse-layer verify: suffix distance + per-query
-    accumulated prefix distance, thresholded — emits (BLOCK_M, BLOCK_N)
-    int32 0/1 survival masks plus the exact int32 total distances
-    (clamped to BIG on pruned lanes)."""
-    dist = _tile_distances(db_ref[...], q_ref[...], b=b, W=W)
-    total = dist + base_ref[...]                  # (BLOCK_M, BLOCK_N)
-    mask_ref[...] = (total <= tau).astype(jnp.int32)
-    dist_ref[...] = jnp.minimum(total, BIG)
+def _verify_kernel(db_ref, q_ref, base_ref, dist_ref, *, b: int, W: int):
+    """Fused query-tiled verify: suffix distance + per-query accumulated
+    prefix distance -> the exact int32 total, clamped to BIG on pruned
+    lanes (base >= BIG)."""
+    dist = _tile_distances(db_ref, q_ref, b=b, W=W)
+    dist_ref[...] = jnp.minimum(dist + base_ref[...], BIG)
+
+
+def _packed_tile_distances(db, q, *, b: int, S: int):
+    """(1, BLOCK_N) uint32 packed suffixes x (BLOCK_M, 1) uint32 packed
+    query suffixes -> (BLOCK_M, BLOCK_N) int32 Hamming distances over
+    the S suffix positions.  All b planes of a row live in ONE word
+    (plane i at bit offset i·S, see ``hamming.pack_suffix_words``), so
+    the XOR/OR fold runs as b-1 shift+mask+OR word ops before a single
+    popcount — the vertical-format identity at 1/W·b of the full-length
+    traffic."""
+    x = db ^ q                                    # (BLOCK_M, BLOCK_N)
+    field = jnp.uint32((1 << S) - 1) if S else jnp.uint32(0)
+    acc = x & field
+    for i in range(1, b):
+        acc = acc | ((x >> jnp.uint32(i * S)) & field)
+    return jax.lax.population_count(acc).astype(jnp.int32)
+
+
+def _verify_packed_kernel(db_ref, q_ref, base_ref, dist_ref, *, b: int,
+                          S: int):
+    """Packed-suffix twin of ``_verify_kernel``: the per-column payload
+    is one uint32 word (the b bit planes of the S-symbol suffix below
+    the segment's ℓ_s collapse depth) instead of (b, W) full-length
+    words — the prefix part of the distance arrives through the base
+    plane (DESIGN.md §7)."""
+    dist = _packed_tile_distances(db_ref[...], q_ref[...], b=b, S=S)
+    dist_ref[...] = jnp.minimum(dist + base_ref[...], BIG)
+
+
+def _verify_call(kernel, db, q_rows, base, *, tau: int, block_m: int,
+                 block_n: int, interpret: bool):
+    """Launch one verify kernel on the (m/block_m, n/block_n) grid and
+    return ((m, n) int32 survival masks, (m, n) int32 BIG-clamped
+    totals); the mask is derived from the one emitted plane."""
+    m, n = base.shape
+    assert n % block_n == 0, (n, block_n)
+    assert m % block_m == 0, (m, block_m)
+    assert db.shape[-1] == n and q_rows.shape[0] == m, (db.shape,
+                                                        q_rows.shape)
+    dist = pl.pallas_call(
+        kernel,
+        grid=(m // block_m, n // block_n),
+        in_specs=_scan_specs(db.shape, q_rows.shape[1], block_m, block_n)
+        + [_tile_spec(block_m, block_n)],
+        out_specs=_tile_spec(block_m, block_n),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        interpret=interpret,
+    )(db, q_rows, base.astype(jnp.int32))
+    return (dist <= tau).astype(jnp.int32), dist
 
 
 @functools.partial(jax.jit,
@@ -120,37 +200,16 @@ def sparse_verify_batch_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     distances -> ((m, n) int32 survival masks, (m, n) int32 totals).
 
     Grid (m/block_m, n/block_n): each cell loads one (b, W, block_n)
-    database block once and XOR/popcounts it against a (b, W, block_m)
-    query tile, so the collapsed-path array is streamed from HBM only
+    database block once and XOR/popcounts it against a block_m-query
+    tile, so the collapsed-path array is streamed from HBM only
     ⌈m/block_m⌉ times for the whole batch.  Distances are exact
     (prefix + suffix) for every non-pruned lane and clamped to BIG where
     the prefix was pruned (base >= BIG)."""
-    b, W, n = paths_vert.shape
-    m = q_vert.shape[-1]
-    assert n % block_n == 0, (n, block_n)
-    assert m % block_m == 0, (m, block_m)
-    assert base_dist.shape == (m, n), (base_dist.shape, m, n)
-    grid = (m // block_m, n // block_n)
-    kernel = functools.partial(_verify_batch_kernel, b=b, W=W, tau=tau)
-    mask, dist = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, W, block_n), lambda j, i: (0, 0, i)),
-            pl.BlockSpec((b, W, block_m), lambda j, i: (0, 0, j)),
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(paths_vert, q_vert, base_dist.astype(jnp.int32))
-    return mask, dist
+    b, W, _ = paths_vert.shape
+    kernel = functools.partial(_verify_kernel, b=b, W=W)
+    return _verify_call(kernel, paths_vert, _query_rows(q_vert), base_dist,
+                        tau=tau, block_m=block_m, block_n=block_n,
+                        interpret=interpret)
 
 
 def sparse_verify_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
@@ -165,36 +224,18 @@ def sparse_verify_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     return mask[0], dist[0]
 
 
-def _packed_tile_distances(db, q, *, b: int, S: int):
-    """(BLOCK_N,) uint32 packed suffixes x (BLOCK_M,) uint32 packed query
-    suffixes -> (BLOCK_M, BLOCK_N) int32 Hamming distances over the S
-    suffix positions.  All b planes of a row live in ONE word (plane i at
-    bit offset i·S, see ``hamming.pack_suffix_words``), so the XOR/OR
-    fold runs as b-1 shift+mask+OR word ops before a single popcount —
-    the vertical-format identity at 1/W·b of the full-length traffic."""
-    x = db[None, :] ^ q[:, None]                  # (BLOCK_M, BLOCK_N)
-    field = jnp.uint32((1 << S) - 1) if S else jnp.uint32(0)
-    acc = x & field
-    for i in range(1, b):
-        acc = acc | ((x >> jnp.uint32(i * S)) & field)
-    return jax.lax.population_count(acc).astype(jnp.int32)
-
-
-def _verify_arena_packed_kernel(db_ref, q_ref, base_ref, idx_ref, live_ref,
-                                mask_ref, dist_ref, *, b: int, S: int,
-                                tau: int):
-    """Packed-suffix twin of ``_verify_arena_kernel``: identical base
-    gather / liveness / threshold semantics, but the per-column payload
-    is one uint32 word (the b bit planes of the S-symbol suffix below
-    the segment's ℓ_s collapse depth) instead of (b, W) full-length
-    words — the prefix part of the distance arrives through the gathered
-    base plane (DESIGN.md §7)."""
-    dist = _packed_tile_distances(db_ref[...], q_ref[...], b=b, S=S)
-    base = jnp.take(base_ref[...], idx_ref[...], axis=1)  # (BLOCK_M, BLOCK_N)
-    base = jnp.where(live_ref[...][None, :] != 0, base, BIG)
-    total = dist + base
-    mask_ref[...] = (total <= tau).astype(jnp.int32)
-    dist_ref[...] = jnp.minimum(total, BIG)
+def _gather_base(base_plane: jnp.ndarray, base_idx: jnp.ndarray,
+                 live: jnp.ndarray) -> jnp.ndarray:
+    """(m, T) per-root base plane -> the dense (m, n) per-column base
+    plane the verify kernels stream: an XLA gather through the
+    segment-offset lane, BIG on dead lanes (DESIGN.md §6).  One 1-D
+    gather per query row (``lax.map``): a single (m, T)[:, idx] gather
+    lays its slices out as (n, m) and pads m to 128 lanes on TPU — a
+    temporary of 512 bytes per column."""
+    live = live != 0
+    return jax.lax.map(
+        lambda row: jnp.where(live, row[base_idx], BIG),
+        base_plane.astype(jnp.int32))
 
 
 @functools.partial(jax.jit,
@@ -223,54 +264,15 @@ def sparse_verify_arena_packed_pallas(db_words: jnp.ndarray,
     from b·W words to one."""
     n = db_words.shape[-1]
     m = q_words.shape[-1]
-    T = base_plane.shape[-1]
-    assert n % block_n == 0, (n, block_n)
-    assert m % block_m == 0, (m, block_m)
-    assert base_plane.shape == (m, T), (base_plane.shape, m, T)
+    assert base_plane.shape[0] == m, (base_plane.shape, m)
     assert base_idx.shape == (n,), (base_idx.shape, n)
     assert live.shape == (n,), (live.shape, n)
-    grid = (m // block_m, n // block_n)
-    kernel = functools.partial(_verify_arena_packed_kernel, b=b, S=S, tau=tau)
-    mask, dist = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-            pl.BlockSpec((block_m,), lambda j, i: (j,)),
-            pl.BlockSpec((block_m, T), lambda j, i: (j, 0)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(db_words.astype(jnp.uint32), q_words.astype(jnp.uint32),
-      base_plane.astype(jnp.int32), base_idx.astype(jnp.int32),
-      live.astype(jnp.int32))
-    return mask, dist
-
-
-def _verify_arena_kernel(db_ref, q_ref, base_ref, idx_ref, live_ref,
-                         mask_ref, dist_ref, *, b: int, W: int, tau: int):
-    """One (query tile j, column block i) cell of the arena verify: the
-    per-column base distance is *gathered* through the segment-offset
-    lane instead of arriving as a dense (m, n) plane — ``base_ref`` is
-    the whole (BLOCK_M, T) concatenated per-root base plane for this
-    query tile, ``idx_ref`` the (BLOCK_N,) int32 plane index of each
-    column in the block, ``live_ref`` its (BLOCK_N,) int32 liveness lane
-    (0 = tombstoned; pruned exactly like an unreached subtrie)."""
-    dist = _tile_distances(db_ref[...], q_ref[...], b=b, W=W)
-    base = jnp.take(base_ref[...], idx_ref[...], axis=1)  # (BLOCK_M, BLOCK_N)
-    base = jnp.where(live_ref[...][None, :] != 0, base, BIG)
-    total = dist + base                                   # (BLOCK_M, BLOCK_N)
-    mask_ref[...] = (total <= tau).astype(jnp.int32)
-    dist_ref[...] = jnp.minimum(total, BIG)
+    kernel = functools.partial(_verify_packed_kernel, b=b, S=S)
+    return _verify_call(
+        kernel, db_words.astype(jnp.uint32).reshape(1, n),
+        q_words.astype(jnp.uint32).reshape(m, 1),
+        _gather_base(base_plane, base_idx, live), tau=tau, block_m=block_m,
+        block_n=block_n, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -298,73 +300,47 @@ def sparse_verify_arena_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     live:       (n,) int32 — per-column liveness lane (0 = tombstoned).
 
     Returns ((m, n) int32 survival masks, (m, n) int32 totals clamped to
-    BIG).  Grid is the same (m/block_m, n/block_n) as
-    ``sparse_verify_batch_pallas`` — one launch sweeps every segment and
-    the delta buffer — but HBM traffic for the base term drops from an
-    (m, n) dense plane to (m, T) + (n,) int32 lanes (T = total ℓ_s
-    roots ≪ n).  The in-kernel gather is a lane-axis ``jnp.take`` per
-    (BLOCK_M, BLOCK_N) cell; on older Mosaic versions without dynamic
-    lane gathers, fall back to ``sparse_verify_batch_pallas`` with a
-    pre-gathered plane (``ops.sparse_verify_arena(use_kernel=False)``
-    takes that path through the oracle)."""
+    BIG).  One launch sweeps every segment and the delta buffer: the
+    per-column base is gathered through the segment-offset lane by XLA
+    into a dense (m, n) plane (Mosaic has no lane gather), which the
+    ``sparse_verify_batch_pallas`` kernel body then streams."""
     b, W, n = paths_vert.shape
     m = q_vert.shape[-1]
-    T = base_plane.shape[-1]
-    assert n % block_n == 0, (n, block_n)
-    assert m % block_m == 0, (m, block_m)
-    assert base_plane.shape == (m, T), (base_plane.shape, m, T)
+    assert base_plane.shape[0] == m, (base_plane.shape, m)
     assert base_idx.shape == (n,), (base_idx.shape, n)
     assert live.shape == (n,), (live.shape, n)
-    grid = (m // block_m, n // block_n)
-    kernel = functools.partial(_verify_arena_kernel, b=b, W=W, tau=tau)
-    mask, dist = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, W, block_n), lambda j, i: (0, 0, i)),
-            pl.BlockSpec((b, W, block_m), lambda j, i: (0, 0, j)),
-            pl.BlockSpec((block_m, T), lambda j, i: (j, 0)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(paths_vert, q_vert, base_plane.astype(jnp.int32),
-      base_idx.astype(jnp.int32), live.astype(jnp.int32))
-    return mask, dist
+    kernel = functools.partial(_verify_kernel, b=b, W=W)
+    return _verify_call(kernel, paths_vert, _query_rows(q_vert),
+                        _gather_base(base_plane, base_idx, live), tau=tau,
+                        block_m=block_m, block_n=block_n,
+                        interpret=interpret)
 
 
 def _rerank_kernel(pay_ref, q_ref, surv_ref, out_ref, *, Wp: int,
                    metric: str):
     """One (query tile j, column block i) cell of the exact re-rank plane:
     AND/popcount the (Wp, BLOCK_N) payload bitmaps against a
-    (Wp, BLOCK_M) query tile, reduce the word axis, and emit the exact
-    set-similarity score for every survivor lane.  Non-survivors (and
-    zero-denominator survivors' 0.0) keep the layout of the Hamming
-    plane so the downstream top-k sort needs no re-gather.  Like
-    ``_tile_distances``, Wp is a python constant and the word reduction
-    fully unrolls on the sublane axis."""
-    pay = pay_ref[...]                            # (Wp, BLOCK_N)
-    q = q_ref[...]                                # (Wp, BLOCK_M)
-    both = jax.lax.population_count(q[:, :, None] & pay[:, None, :])
-    pa = jax.lax.population_count(q).astype(jnp.int32)    # (Wp, BLOCK_M)
-    pb = jax.lax.population_count(pay).astype(jnp.int32)  # (Wp, BLOCK_N)
-    inter = both[0].astype(jnp.int32)
-    sa, sb = pa[0], pb[0]
-    for w in range(1, Wp):
-        inter = inter + both[w].astype(jnp.int32)
-        sa = sa + pa[w]
-        sb = sb + pb[w]
+    (BLOCK_M, Wp) query tile one word at a time — a (BLOCK_M, 1) query
+    column against a (1, BLOCK_N) payload row, so no intermediate grows
+    with Wp — and emit the exact set-similarity score for every survivor
+    lane.  Non-survivors (and zero-denominator survivors' 0.0) keep the
+    layout of the Hamming plane so the downstream top-k sort needs no
+    re-gather.  Wp is a python constant and the word loop fully
+    unrolls."""
+    inter = sa = sb = None
+    for w in range(Wp):
+        p = pay_ref[w:w + 1, :]                   # (1, BLOCK_N)
+        q = q_ref[:, w:w + 1]                     # (BLOCK_M, 1)
+        both = jax.lax.population_count(q & p).astype(jnp.int32)
+        pa = jax.lax.population_count(q).astype(jnp.int32)
+        pb = jax.lax.population_count(p).astype(jnp.int32)
+        if inter is None:
+            inter, sa, sb = both, pa, pb
+        else:
+            inter, sa, sb = inter + both, sa + pa, sb + pb
     inter = inter.astype(jnp.float32)             # (BLOCK_M, BLOCK_N)
-    sa = sa.astype(jnp.float32)[:, None]
-    sb = sb.astype(jnp.float32)[None, :]
+    sa = sa.astype(jnp.float32)                   # (BLOCK_M, 1)
+    sb = sb.astype(jnp.float32)                   # (1, BLOCK_N)
     if metric == "jaccard":
         den = sa + sb - inter
     elif metric == "cosine":
@@ -391,7 +367,8 @@ def exact_rerank_pallas(pay_vert: jnp.ndarray, q_vert: jnp.ndarray,
     the whole arena, reading the payload store once per query tile.
     Scores are exact Jaccard / cosine / containment over the uint32
     set bitmaps (see ``kernels.ref.exact_rerank_ref`` for semantics);
-    non-survivor lanes emit the -1.0 sentinel.
+    non-survivor lanes emit the -1.0 sentinel.  Raises ValueError when
+    the payload width's blocks exceed ``RERANK_VMEM_BUDGET``.
     """
     Wp, n = pay_vert.shape
     m = q_vert.shape[-1]
@@ -399,18 +376,25 @@ def exact_rerank_pallas(pay_vert: jnp.ndarray, q_vert: jnp.ndarray,
     assert n % block_n == 0, (n, block_n)
     assert m % block_m == 0, (m, block_m)
     assert surv.shape == (m, n), (surv.shape, m, n)
-    grid = (m // block_m, n // block_n)
+    # double-buffered blocks: the (Wp, block_n) payload block, the
+    # (block_m, Wp) query tile (lanes padded to 128), the survivor-mask
+    # and score tiles
+    lanes = -(-Wp // 128) * 128
+    need = 2 * 4 * (Wp * block_n + block_m * lanes + 2 * block_m * block_n)
+    if need > RERANK_VMEM_BUDGET:
+        raise ValueError(
+            f"exact re-rank payload width Wp={Wp} words needs {need} bytes "
+            f"of VMEM blocks at block_m={block_m}, block_n={block_n}; the "
+            f"budget is {RERANK_VMEM_BUDGET} (use a smaller vocabulary or "
+            f"block_n)")
     kernel = functools.partial(_rerank_kernel, Wp=Wp, metric=metric)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((Wp, block_n), lambda j, i: (0, i)),
-            pl.BlockSpec((Wp, block_m), lambda j, i: (0, j)),
-            pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda j, i: (j, i)),
+        grid=(m // block_m, n // block_n),
+        in_specs=_scan_specs(pay_vert.shape, Wp, block_m, block_n)
+        + [_tile_spec(block_m, block_n)],
+        out_specs=_tile_spec(block_m, block_n),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(pay_vert.astype(jnp.uint32), q_vert.astype(jnp.uint32),
+    )(pay_vert.astype(jnp.uint32), q_vert.astype(jnp.uint32).T,
       surv.astype(jnp.int32))

@@ -24,8 +24,18 @@ from .hamming_kernel import (BIG, DEFAULT_BLOCK_M, DEFAULT_BLOCK_N,
                              sparse_verify_batch_pallas, sparse_verify_pallas)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas mode for the current backend: compiled on TPU, interpret
+    mode on CPU (the test path — same kernel body, executed by the
+    interpreter).  Any other backend raises rather than serving answers
+    from a path that hides the missing device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or in interpret "
+                       f"mode on CPU; the {backend!r} backend is neither")
 
 
 # Process-wide kernel-build ledger (DESIGN.md §11): one bump per wrapper
@@ -88,7 +98,7 @@ def hamming_distances(db_vert: jnp.ndarray, q_vert: jnp.ndarray,
     db_p = _pad_lanes(db_vert, block_n)
     q_p = _pad_lanes(q_vert, block_m)
     out = hamming_distances_pallas(db_p, q_p, block_m=block_m,
-                                   block_n=block_n, interpret=not _on_tpu())
+                                   block_n=block_n, interpret=_interpret())
     return out[:m, :n]
 
 
@@ -120,7 +130,7 @@ def sparse_verify(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     pad = paths_p.shape[-1] - n
     base_p = jnp.pad(base_dist.astype(jnp.int32), (0, pad), constant_values=jnp.int32(BIG))
     mask, dist = sparse_verify_pallas(paths_p, q_vert, base_p, tau=tau,
-                                      block_n=block_n, interpret=not _on_tpu())
+                                      block_n=block_n, interpret=_interpret())
     return mask[:n], dist[:n]
 
 
@@ -167,7 +177,7 @@ def sparse_verify_batch(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
                      constant_values=jnp.int32(BIG))
     mask, dist = sparse_verify_batch_pallas(paths_p, q_p, base_p, tau=tau,
                                             block_m=block_m, block_n=block_n,
-                                            interpret=not _on_tpu())
+                                            interpret=_interpret())
     return mask[:m, :n], dist[:m, :n]
 
 
@@ -191,8 +201,8 @@ def sparse_verify_arena(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
 
     One launch sweeps every segment and the delta buffer: pads n to a
     ``block_n`` multiple with dead lanes (live=False -> BIG, can never
-    survive), m to a ``block_m`` multiple with all-zero queries (rows
-    sliced off), and T to a lane multiple with BIG (never indexed)."""
+    survive) and m to a ``block_m`` multiple with all-zero queries (rows
+    sliced off)."""
     n = paths_vert.shape[-1]
     m = q_vert.shape[-1]
     if use_kernel is None:
@@ -208,15 +218,13 @@ def sparse_verify_arena(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     q_p = _pad_lanes(q_vert, block_m)
     pad_n = paths_p.shape[-1] - n
     pad_m = q_p.shape[-1] - m
-    pad_t = (-base_plane.shape[-1]) % 128    # lane-align the plane axis
-    base_p = jnp.pad(base_plane.astype(jnp.int32),
-                     ((0, pad_m), (0, pad_t)),
+    base_p = jnp.pad(base_plane.astype(jnp.int32), ((0, pad_m), (0, 0)),
                      constant_values=jnp.int32(BIG))
     idx_p = jnp.pad(base_idx.astype(jnp.int32), (0, pad_n))
     live_p = jnp.pad(live.astype(jnp.int32), (0, pad_n))  # pads dead
     mask, dist = sparse_verify_arena_pallas(
         paths_p, q_p, base_p, idx_p, live_p, tau=tau, block_m=block_m,
-        block_n=block_n, interpret=not _on_tpu())
+        block_n=block_n, interpret=_interpret())
     return mask[:m, :n], dist[:m, :n]
 
 
@@ -241,7 +249,7 @@ def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
     returns ((m, n) int32 masks, (m, n) int32 totals, BIG-clamped).
 
     Same padding discipline as ``sparse_verify_arena``: n pads with dead
-    lanes, m with all-zero queries, T to a lane multiple with BIG."""
+    lanes, m with all-zero queries."""
     n = db_words.shape[-1]
     m = q_words.shape[-1]
     if use_kernel is None:
@@ -256,15 +264,13 @@ def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
     q_p = _pad_lanes(q_words.astype(jnp.uint32), block_m)
     pad_n = db_p.shape[-1] - n
     pad_m = q_p.shape[-1] - m
-    pad_t = (-base_plane.shape[-1]) % 128    # lane-align the plane axis
-    base_p = jnp.pad(base_plane.astype(jnp.int32),
-                     ((0, pad_m), (0, pad_t)),
+    base_p = jnp.pad(base_plane.astype(jnp.int32), ((0, pad_m), (0, 0)),
                      constant_values=jnp.int32(BIG))
     idx_p = jnp.pad(base_idx.astype(jnp.int32), (0, pad_n))
     live_p = jnp.pad(live.astype(jnp.int32), (0, pad_n))  # pads dead
     mask, dist = sparse_verify_arena_packed_pallas(
         db_p, q_p, base_p, idx_p, live_p, b=b, S=S, tau=tau,
-        block_m=block_m, block_n=block_n, interpret=not _on_tpu())
+        block_m=block_m, block_n=block_n, interpret=_interpret())
     return mask[:m, :n], dist[:m, :n]
 
 
@@ -300,5 +306,5 @@ def exact_rerank(pay_vert: jnp.ndarray, q_vert: jnp.ndarray,
     surv_p = jnp.pad(surv.astype(jnp.int32), ((0, pad_m), (0, pad_n)))
     out = exact_rerank_pallas(pay_p, q_p, surv_p, metric=metric,
                               block_m=block_m, block_n=block_n,
-                              interpret=not _on_tpu())
+                              interpret=_interpret())
     return out[:m, :n]

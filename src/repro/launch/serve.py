@@ -34,6 +34,7 @@ from ..core.hamming import pack_sets
 from ..core.sketch import zbit_cws
 from ..kernels.hamming_kernel import DEFAULT_BLOCK_M
 from ..distributed.sharding import use_mesh
+from ..launch.compile_cache import use_compile_cache
 from ..launch.mesh import make_host_mesh
 from ..models import model as M
 from ..obs import SlowQueryLog, Tracer
@@ -290,6 +291,7 @@ def main(argv=None):
                          "slow-query log")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.ingest:
         return run_ingest(args)

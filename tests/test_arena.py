@@ -58,23 +58,38 @@ def assert_topk_equal(idx, qs, k, tau0=None):
     (300, 5, 17, 128, 8),      # pad on both axes
     (256, 1, 3, 128, 8),       # m=1 degenerate tile, aligned n
     (130, 9, 200, 128, 4),     # tile-misaligned both ways, T > n block
+    (300, 8, 17, 128, 8),      # the scheduler's buckets at the default
+    (300, 16, 17, 128, 8),     #   query tile, pad lanes
+    (390, 64, 33, 128, 24),    # pad rows: 64 queries in 24-row tiles
 ])
 def test_arena_kernel_matches_oracle(n, m, T, block_n, block_m):
+    """Both arena kernels — full-length vertical columns and packed
+    suffix words — against their oracles, bit for bit."""
     rng = np.random.default_rng(n + m)
     b, W = 3, 2
     paths = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, n), np.uint64)
                         .astype(np.uint32))
     q = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, m), np.uint64)
                     .astype(np.uint32))
-    base = np.where(rng.random((m, T)) < 0.3, BIG_I,
-                    rng.integers(0, 5, (m, T))).astype(np.int32)
-    idx = rng.integers(0, T, n).astype(np.int32)
-    live = rng.random(n) < 0.8
+    base = jnp.asarray(np.where(rng.random((m, T)) < 0.3, BIG_I,
+                                rng.integers(0, 5, (m, T))).astype(np.int32))
+    idx = jnp.asarray(rng.integers(0, T, n).astype(np.int32))
+    live = jnp.asarray(rng.random(n) < 0.8)
     mk, dk = ops.sparse_verify_arena(
-        paths, q, jnp.asarray(base), jnp.asarray(idx), jnp.asarray(live),
+        paths, q, base, idx, live,
         tau=20, block_n=block_n, block_m=block_m, use_kernel=True)
-    mo, do = ref.sparse_verify_arena_ref(
-        paths, q, jnp.asarray(base), jnp.asarray(idx), jnp.asarray(live), 20)
+    mo, do = ref.sparse_verify_arena_ref(paths, q, base, idx, live, 20)
+    np.testing.assert_array_equal(np.asarray(mk),
+                                  np.asarray(mo).astype(np.int32))
+    np.testing.assert_array_equal(np.asarray(dk), np.asarray(do))
+
+    S = 10                                     # b·S = 30 of 32 bits
+    words, q_words = paths[0, 0], q[0, 0]
+    mk, dk = ops.sparse_verify_arena_packed(
+        words, q_words, base, idx, live, b=b, S=S, tau=6, block_n=block_n,
+        block_m=block_m, use_kernel=True)
+    mo, do = ref.sparse_verify_arena_packed_ref(words, q_words, base, idx,
+                                                live, b, S, 6)
     np.testing.assert_array_equal(np.asarray(mk),
                                   np.asarray(mo).astype(np.int32))
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(do))

@@ -44,6 +44,10 @@ def make_db(rng, n, L, b):
     (8, 384, 4, 128),    # both exact multiples
     (1, 200, 4, 128),    # m=1 degenerate tile (m < block_m)
     (3, 100, 8, 256),    # n < block_n entirely inside one padded block
+    (1, 300, 8, 128),    # serving buckets at the default tile: pad lanes
+    (8, 300, 8, 128),
+    (16, 300, 8, 128),
+    (64, 390, 24, 128),  # pad rows: 64 queries in 24-row tiles
 ])
 def test_batch_verify_matches_per_query_oracle(b, L, tau, m, n, block_m,
                                                block_n):

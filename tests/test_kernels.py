@@ -22,7 +22,9 @@ def make_db(rng, n, L, b):
 
 
 @pytest.mark.parametrize("b,L", [(2, 16), (2, 32), (4, 32), (8, 64), (1, 8), (4, 100)])
-@pytest.mark.parametrize("n,m,block_n", [(256, 3, 128), (512, 1, 512), (130, 2, 128)])
+@pytest.mark.parametrize("n,m,block_n", [(256, 3, 128), (512, 1, 512), (130, 2, 128),
+                                         (300, 8, 128), (300, 16, 128),
+                                         (390, 64, 128)])
 def test_hamming_kernel_matches_oracle(b, L, n, m, block_n):
     rng = np.random.default_rng(b * 1000 + L + n)
     db, db_vert = make_db(rng, n, L, b)
@@ -79,6 +81,17 @@ def test_small_path_uses_oracle():
     got = np.asarray(ops.hamming_distances(db_vert, q_vert))  # n < block -> oracle
     want = np.asarray(ref.hamming_distances_ref(db_vert, q_vert))
     np.testing.assert_array_equal(got, want)
+
+
+def test_kernel_path_refuses_backends_without_a_compiled_kernel(monkeypatch):
+    """Interpret mode is the CPU test path only: a host whose accelerator
+    failed to start must not serve answers through it silently."""
+    rng = np.random.default_rng(2)
+    _, db_vert = make_db(rng, 256, 16, 2)
+    _, q_vert = make_db(rng, 2, 16, 2)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' backend"):
+        ops.hamming_distances(db_vert, q_vert, block_n=128, use_kernel=True)
 
 
 @settings(max_examples=15, deadline=None)

@@ -104,7 +104,8 @@ def check_rerank(idx, qs, qp, k, metric, want_rerank_launches=1):
 # -- kernel vs oracle vs numpy ------------------------------------------
 
 @pytest.mark.parametrize("metric", RERANK_METRICS)
-@pytest.mark.parametrize("m,n", [(1, 70), (5, 64), (3, 130), (8, 200)])
+@pytest.mark.parametrize("m,n", [(1, 70), (5, 64), (3, 130), (8, 200),
+                                 (16, 130), (64, 200)])
 def test_kernel_bit_exact_vs_oracle_and_numpy(metric, m, n):
     """Pad rows (m % block_m != 0), tile-misaligned n, m=1 — the pallas
     kernel, the jnp oracle, and the numpy brute force all agree bit for
@@ -157,6 +158,18 @@ def test_small_scan_routes_to_oracle():
     forced = np.asarray(ops.exact_rerank(pay.T, qp.T, surv,
                                          metric="jaccard", use_kernel=True))
     np.testing.assert_array_equal(auto, forced)
+
+
+def test_payload_width_past_vmem_budget_rejected():
+    """A 32k-token vocabulary (Wp = 1024 words) cannot double-buffer its
+    payload blocks in VMEM at the default lane tile: the wrapper refuses
+    it with a clear error instead of failing inside the TPU compiler."""
+    wp, n, m = 1024, 2048, 8
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.exact_rerank(np.zeros((wp, n), np.uint32),
+                         np.zeros((wp, m), np.uint32),
+                         np.ones((m, n), np.int32), metric="jaccard",
+                         use_kernel=True)
 
 
 def test_unknown_metric_rejected():

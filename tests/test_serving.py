@@ -511,3 +511,28 @@ def test_concurrent_submitters_all_complete():
     for i, nn_id, nn_dist in results:
         assert nn_dist == 0                 # the doc itself (or a dup twin)
         np.testing.assert_array_equal(docs[nn_id], docs[i])
+
+
+def test_compile_cache_honours_env_else_one_checkout_dir(monkeypatch):
+    """The serving entry points keep JAX's persistent compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (setting nothing in code), else at
+    one fixed directory inside the checkout — never a temporary name."""
+    import pathlib
+
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = use_compile_cache()
+        assert use_compile_cache() == first
+        assert jax.config.jax_compilation_cache_dir == first
+        root = pathlib.Path(first).parent
+        assert pathlib.Path(first).name == ".jax_cache"
+        assert (root / "chip_smoke.py").exists() and (root / "src").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
