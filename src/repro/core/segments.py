@@ -54,8 +54,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import re
 import threading
 import time
+import weakref
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -333,6 +335,121 @@ class _BoundProgram:
 
     def __call__(self, *args):
         return self.jit(self.corpus, *args)
+
+    def lower(self, *args):
+        return self.jit.lower(self.corpus, *args)
+
+
+# Named device stages of the fused rung program (``jax.named_scope`` in
+# ``_build_fused_bst[_suffix]``).  A TPU trace names each device op by
+# its HLO instruction alone, so while a span is attached ``_fused_call``
+# records, per compiled program variant, which scope each instruction of
+# the optimized module came from (``hlo_scopes``) and labels the
+# ``rung_dispatch`` span with the variant (``args["program"]``): a trace
+# op inside that span resolves through ``fused_scope_tables()[label]``.
+# JAX's persistent compile cache keys a program without its debug info,
+# so an executable it returns keeps the metadata of whichever program
+# compiled it first: a table of a program compiled without scopes (an
+# older checkout sharing the cache) holds none.
+RUNG_SCOPES = ("rung.traverse", "rung.verify", "rung.delta_scan",
+               "rung.select")
+_SCOPE_LABELS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_SCOPE_TABLES: Dict[str, Dict[str, str]] = {}
+_SCOPE_SERIALS = itertools.count()
+_SCOPE_LOCK = threading.Lock()
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([^\s(]+) ")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%([^\s=]+) = (.*)$")
+_HLO_SCOPE = re.compile(r'op_name="[^"]*?(rung\.[a-z_]+)')
+_HLO_CALLS = re.compile(r"calls=%([^\s,]+)")
+_HLO_REF = re.compile(r"%([^\s,)}]+)")
+
+
+def hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{instruction: scope}`` for the instructions of an optimized HLO
+    module's text that belong to a ``rung.*`` scope.  An instruction's
+    own ``op_name`` metadata decides; one without metadata (a fusion may
+    carry none) takes the scope of the computation it calls (its root's,
+    else the one scope inside it); one the compiler made from nothing
+    (the reduce-windows of a scan, a pad) takes the one scope of its
+    users, else of its operands."""
+    body: Dict[str, List[str]] = {}
+    refs: Dict[str, List[str]] = {}
+    own: Dict[str, str] = {}
+    calls: Dict[str, str] = {}
+    roots: Dict[str, str] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            body[comp] = []
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or comp is None:
+            continue
+        root, name, rest = m.groups()
+        if " parameter(" in rest:
+            continue
+        body[comp].append(name)
+        refs[name] = _HLO_REF.findall(rest.split(", metadata=", 1)[0])
+        hit = _HLO_SCOPE.search(rest)
+        if hit:
+            own[name] = hit.group(1)
+        callee = _HLO_CALLS.search(rest)
+        if callee:
+            calls[name] = callee.group(1)
+        if root:
+            roots[comp] = name
+    scope = dict(own)
+    for name, callee in calls.items():
+        inner = {own[i] for i in body.get(callee, ()) if i in own}
+        if name not in scope and (roots.get(callee) in own
+                                  or len(inner) == 1):
+            scope[name] = own.get(roots.get(callee)) or inner.pop()
+    users: Dict[str, List[str]] = {name: [] for name in refs}
+    for name, rs in refs.items():
+        for r in rs:
+            if r in users:
+                users[r].append(name)
+    changed = True
+    while changed:
+        changed = False
+        for name in refs:
+            if name in scope:
+                continue
+            for near in (users[name], refs[name]):
+                found = {scope[n] for n in near if n in scope}
+                if len(found) == 1:
+                    scope[name] = found.pop()
+                    changed = True
+                    break
+    return scope
+
+
+def _scope_label(fn, args: tuple) -> str:
+    """The label of the compiled variant of ``fn`` that ``args`` ran,
+    recording its scope table on first sight (a lowering of an already
+    compiled signature: a re-trace, no backend compile)."""
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    sig = (tree, tuple((a.shape, str(a.dtype)) for a in leaves))
+    with _SCOPE_LOCK:
+        labels = _SCOPE_LABELS.setdefault(fn, {})
+        label = labels.get(sig)
+    if label is None:
+        table = hlo_scopes(fn.lower(*args).compile().as_text())
+        label = f"fused.{next(_SCOPE_SERIALS)}"
+        with _SCOPE_LOCK:
+            _SCOPE_TABLES[label] = table
+            labels[sig] = label
+    return label
+
+
+def fused_scope_tables() -> Dict[str, Dict[str, str]]:
+    """Every scope table recorded so far: ``{label: {instruction:
+    scope}}``, the label being a traced ``rung_dispatch`` span's
+    ``args["program"]``."""
+    with _SCOPE_LOCK:
+        return dict(_SCOPE_TABLES)
 
 
 def _take_columns(plane: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -761,14 +878,15 @@ class SegmentedIndex:
         live = self._delta_live
         seg = None
         if live.any():
-            sk = self._delta_sk[live]
-            ids = self._delta_ids[live]
-            pay = (self._delta_pay[live]
-                   if self._delta_pay is not None else None)
-            seg = Segment(index=self._build(sk),
-                          packed=pack_vertical(sk, self.b), ids=ids,
-                          live=np.ones(len(ids), bool), L=self.L, b=self.b,
-                          payloads=pay)
+            with _obs_span("seal", cat="ingest", rows=int(live.sum())):
+                sk = self._delta_sk[live]
+                ids = self._delta_ids[live]
+                pay = (self._delta_pay[live]
+                       if self._delta_pay is not None else None)
+                seg = Segment(index=self._traced_build(sk),
+                              packed=self._traced_pack(sk), ids=ids,
+                              live=np.ones(len(ids), bool), L=self.L,
+                              b=self.b, payloads=pay)
             self.segments.append(seg)
             self.counters["flushes"] += 1
             self._emit("flush", rows=seg.n)
@@ -798,22 +916,27 @@ class SegmentedIndex:
         if i == j:
             raise ValueError("cannot merge a segment with itself")
         a, b_ = self.segments[i], self.segments[j]
-        sk = np.concatenate([a.sketches[a.live], b_.sketches[b_.live]])
-        ids = np.concatenate([a.ids[a.live], b_.ids[b_.live]])
-        pay = None
-        if self.payload_words is not None:
-            pay = np.concatenate([a.payloads[a.live], b_.payloads[b_.live]])
-        order = np.argsort(ids, kind="stable")   # keep ids sorted for delete
-        sk, ids = sk[order], ids[order]
-        if pay is not None:
-            pay = pay[order]
-        lo, hi = min(i, j), max(i, j)
-        del self.segments[hi], self.segments[lo]
-        if len(ids):
-            self.segments.insert(lo, Segment(
-                index=self._build(sk), packed=pack_vertical(sk, self.b),
-                ids=ids, live=np.ones(len(ids), bool), L=self.L, b=self.b,
-                payloads=pay))
+        with _obs_span("merge", cat="ingest",
+                       rows=int(a.n_live + b_.n_live)):
+            sk = np.concatenate([a.sketches[a.live], b_.sketches[b_.live]])
+            ids = np.concatenate([a.ids[a.live], b_.ids[b_.live]])
+            pay = None
+            if self.payload_words is not None:
+                pay = np.concatenate([a.payloads[a.live],
+                                      b_.payloads[b_.live]])
+            # keep ids sorted for delete
+            order = np.argsort(ids, kind="stable")
+            sk, ids = sk[order], ids[order]
+            if pay is not None:
+                pay = pay[order]
+            lo, hi = min(i, j), max(i, j)
+            del self.segments[hi], self.segments[lo]
+            if len(ids):
+                self.segments.insert(lo, Segment(
+                    index=self._traced_build(sk),
+                    packed=self._traced_pack(sk), ids=ids,
+                    live=np.ones(len(ids), bool), L=self.L, b=self.b,
+                    payloads=pay))
         self.counters["merges"] += 1
         self._emit("merge", rows=int(len(ids)))
         if self.store is not None:
@@ -1188,6 +1311,14 @@ class SegmentedIndex:
                                      max(1, min(self.n_shards, len(sk))),
                                      self.lam)
         return build_bst(sk, self.b, self.lam)
+
+    def _traced_build(self, sk: np.ndarray):
+        with _obs_span("trie_build", cat="ingest", rows=len(sk)):
+            return self._build(sk)
+
+    def _traced_pack(self, sk: np.ndarray) -> np.ndarray:
+        with _obs_span("pack_vertical", cat="ingest", rows=len(sk)):
+            return pack_vertical(sk, self.b)
 
     def _delta_planes(self) -> jnp.ndarray:
         """(b, W, ndb) uint32 delta-buffer verify planes, with the row
@@ -1584,35 +1715,40 @@ class SegmentedIndex:
             m = qsi.shape[0]
             planes = [jnp.zeros((m, 1), jnp.int32)]  # slot 0: delta base
             overflow = jnp.zeros((m,), jnp.int32)
-            for ix, caps, t_root in zip(indexes, caps_list, t_roots):
-                ids, dists, valid, ov, _ = _traverse_frontier_batch(
-                    ix, qsi, tau=tau, caps=caps)
-                # full-length columns recompute the prefix in the XOR:
-                # the plane only carries reached (0) / pruned (BIG)
-                planes.append(scatter_root_plane(
-                    ids, jnp.zeros_like(dists), valid, m, t_root))
-                overflow = overflow + ov
-            base_plane = jnp.concatenate(planes, axis=1)
-            cols = jnp.concatenate([cols0, delta_vert], axis=-1)
-            live = jnp.concatenate([live_sealed, delta_live])
-            base_idx = jnp.concatenate(
-                [idx0, jnp.zeros((delta_vert.shape[-1],), jnp.int32)])
-            q_vert = jnp.transpose(pack_vertical_jax(qsi, b_), (1, 2, 0))
-            hm, dist = ops.sparse_verify_arena(
-                cols, q_vert, base_plane, base_idx, live, tau=tau,
-                block_m=block_m)
-            dist = jnp.where(hm > 0, dist, BIG)
+            with jax.named_scope("rung.traverse"):
+                for ix, caps, t_root in zip(indexes, caps_list, t_roots):
+                    ids, dists, valid, ov, _ = _traverse_frontier_batch(
+                        ix, qsi, tau=tau, caps=caps)
+                    # full-length columns recompute the prefix in the
+                    # XOR: the plane only carries reached (0) / pruned
+                    # (BIG)
+                    planes.append(scatter_root_plane(
+                        ids, jnp.zeros_like(dists), valid, m, t_root))
+                    overflow = overflow + ov
+                base_plane = jnp.concatenate(planes, axis=1)
+            with jax.named_scope("rung.verify"):
+                cols = jnp.concatenate([cols0, delta_vert], axis=-1)
+                live = jnp.concatenate([live_sealed, delta_live])
+                base_idx = jnp.concatenate(
+                    [idx0, jnp.zeros((delta_vert.shape[-1],), jnp.int32)])
+                q_vert = jnp.transpose(pack_vertical_jax(qsi, b_),
+                                       (1, 2, 0))
+                hm, dist = ops.sparse_verify_arena(
+                    cols, q_vert, base_plane, base_idx, live, tau=tau,
+                    block_m=block_m)
+                dist = jnp.where(hm > 0, dist, BIG)
             if kind == "cols":
                 return dist, overflow.sum()
-            if kind == "dist":
-                # two-stage stage 1: the dist plane STAYS on device (the
-                # re-rank program consumes it); only the ladder scalars
-                # cross back (DESIGN.md §10)
-                return (dist, (dist < BIG).sum(axis=1).min(),
-                        overflow.sum())
-            sel_ids, sel_d = select_topk_columns(
-                dist, jnp.concatenate([gids0, delta_gids]), kk)
-            min_surv = (dist < BIG).sum(axis=1).min()
+            with jax.named_scope("rung.select"):
+                if kind == "dist":
+                    # two-stage stage 1: the dist plane STAYS on device
+                    # (the re-rank program consumes it); only the ladder
+                    # scalars cross back (DESIGN.md §10)
+                    return (dist, (dist < BIG).sum(axis=1).min(),
+                            overflow.sum())
+                sel_ids, sel_d = select_topk_columns(
+                    dist, jnp.concatenate([gids0, delta_gids]), kk)
+                min_surv = (dist < BIG).sum(axis=1).min()
             return sel_ids, sel_d, min_surv, overflow.sum()
         return run
 
@@ -1654,65 +1790,74 @@ class SegmentedIndex:
             m = qsi.shape[0]
             planes = [jnp.zeros((m, 1), jnp.int32)]  # slot 0: delta base
             overflow = jnp.zeros((m,), jnp.int32)
-            for ix, caps, t_root in zip(corpus["indexes"], caps_list,
-                                        t_roots):
-                ids, dists, valid, ov, _ = _traverse_frontier_batch(
-                    ix, qsi, tau=tau, caps=caps)
-                planes.append(scatter_root_plane(
-                    ids, dists, valid, m, t_root))
-                overflow = overflow + ov
-            base_plane = jnp.concatenate(planes, axis=1)
+            with jax.named_scope("rung.traverse"):
+                for ix, caps, t_root in zip(corpus["indexes"], caps_list,
+                                            t_roots):
+                    ids, dists, valid, ov, _ = _traverse_frontier_batch(
+                        ix, qsi, tau=tau, caps=caps)
+                    planes.append(scatter_root_plane(
+                        ids, dists, valid, m, t_root))
+                    overflow = overflow + ov
+                base_plane = jnp.concatenate(planes, axis=1)
             order, inv = corpus["order"], corpus["inv"]
             dist_parts: List[jnp.ndarray] = []
-            for gi, (geom, cols_hot, base_idx, slab) in enumerate(zip(
-                    geoms, corpus["cols"], corpus["base_idx"], staged)):
-                axis = 0 if geom.packed else -1
-                parts = [p for p in (cols_hot, slab) if p is not None]
-                cols_g = (parts[0] if len(parts) == 1
-                          else jnp.concatenate(parts, axis=axis))
-                cols = slice(spans[gi], spans[gi + 1])
-                live_g = live_sealed[cols if order is None else order[cols]]
-                S = geom.suffix_len
-                if geom.packed:
-                    qw = pack_suffix_words_jax(qsi[:, L - S:], b_)
-                    hm, d = ops.sparse_verify_arena_packed(
-                        cols_g, qw, base_plane, base_idx, live_g, b=b_,
-                        S=S, tau=tau, block_m=block_m)
-                else:
-                    qv = jnp.transpose(
-                        pack_vertical_jax(qsi[:, L - S:], b_), (1, 2, 0))
-                    hm, d = ops.sparse_verify_arena(
-                        cols_g, qv, base_plane, base_idx, live_g,
-                        tau=tau, block_m=block_m)
-                dist_parts.append(jnp.where(hm > 0, d, BIG))
-            dist_sealed = (jnp.concatenate(dist_parts, axis=1) if dist_parts
-                           else jnp.zeros((m, 0), jnp.int32))
+            with jax.named_scope("rung.verify"):
+                for gi, (geom, cols_hot, base_idx, slab) in enumerate(zip(
+                        geoms, corpus["cols"], corpus["base_idx"], staged)):
+                    axis = 0 if geom.packed else -1
+                    parts = [p for p in (cols_hot, slab) if p is not None]
+                    cols_g = (parts[0] if len(parts) == 1
+                              else jnp.concatenate(parts, axis=axis))
+                    cols = slice(spans[gi], spans[gi + 1])
+                    live_g = live_sealed[cols if order is None
+                                         else order[cols]]
+                    S = geom.suffix_len
+                    if geom.packed:
+                        qw = pack_suffix_words_jax(qsi[:, L - S:], b_)
+                        hm, d = ops.sparse_verify_arena_packed(
+                            cols_g, qw, base_plane, base_idx, live_g, b=b_,
+                            S=S, tau=tau, block_m=block_m)
+                    else:
+                        qv = jnp.transpose(
+                            pack_vertical_jax(qsi[:, L - S:], b_),
+                            (1, 2, 0))
+                        hm, d = ops.sparse_verify_arena(
+                            cols_g, qv, base_plane, base_idx, live_g,
+                            tau=tau, block_m=block_m)
+                    dist_parts.append(jnp.where(hm > 0, d, BIG))
+                dist_sealed = (jnp.concatenate(dist_parts, axis=1)
+                               if dist_parts
+                               else jnp.zeros((m, 0), jnp.int32))
             # the delta buffer scans full-length (its rows have no trie,
             # hence no ℓ_s to slice at) — same arithmetic as the full
             # arena's trivial base slot 0
-            q_vert = jnp.transpose(pack_vertical_jax(qsi, b_), (1, 2, 0))
-            dd = ops.hamming_distances(delta_vert, q_vert)
-            dd = jnp.where(delta_live[None, :] & (dd <= tau), dd, BIG)
-            dd = dd.astype(jnp.int32)
-            if kind == "topk":
-                # selection sorts on (distance, id), so it runs on the
-                # group-major columns with the labels permuted instead
-                gids = (corpus["gids"] if order is None
-                        else corpus["gids"][order])
+            with jax.named_scope("rung.delta_scan"):
+                q_vert = jnp.transpose(pack_vertical_jax(qsi, b_),
+                                       (1, 2, 0))
+                dd = ops.hamming_distances(delta_vert, q_vert)
+                dd = jnp.where(delta_live[None, :] & (dd <= tau), dd, BIG)
+                dd = dd.astype(jnp.int32)
+            with jax.named_scope("rung.select"):
+                if kind == "topk":
+                    # selection sorts on (distance, id), so it runs on
+                    # the group-major columns with the labels permuted
+                    # instead
+                    gids = (corpus["gids"] if order is None
+                            else corpus["gids"][order])
+                    dist = jnp.concatenate([dist_sealed, dd], axis=1)
+                    sel_ids, sel_d = select_topk_columns(
+                        dist, jnp.concatenate([gids, delta_gids]), kk)
+                    min_surv = (dist < BIG).sum(axis=1).min()
+                    return sel_ids, sel_d, min_surv, overflow.sum()
+                # restore global stack order, so the column contract and
+                # the re-rank stage match the full-length arena exactly
+                if inv is not None:
+                    dist_sealed = _take_columns(dist_sealed, inv)
                 dist = jnp.concatenate([dist_sealed, dd], axis=1)
-                sel_ids, sel_d = select_topk_columns(
-                    dist, jnp.concatenate([gids, delta_gids]), kk)
-                min_surv = (dist < BIG).sum(axis=1).min()
-                return sel_ids, sel_d, min_surv, overflow.sum()
-            # restore global stack order, so the column contract and the
-            # re-rank stage match the full-length arena exactly
-            if inv is not None:
-                dist_sealed = _take_columns(dist_sealed, inv)
-            dist = jnp.concatenate([dist_sealed, dd], axis=1)
-            if kind == "cols":
-                return dist, overflow.sum()
-            return (dist, (dist < BIG).sum(axis=1).min(),
-                    overflow.sum())
+                if kind == "cols":
+                    return dist, overflow.sum()
+                return (dist, (dist < BIG).sum(axis=1).min(),
+                        overflow.sum())
         return _BoundProgram(run, corpus)
 
     def _build_fused_multi(self, kind: str, tau: int, rung: int,
@@ -1823,27 +1968,29 @@ class SegmentedIndex:
         if mb != m:
             qs_p = _pad_rows(qs_p, mb)
         nd = len(self._delta_ids)
-        if nd:
-            delta_vert = self._delta_planes()
-            ndb = delta_vert.shape[-1]
-            delta_live = np.zeros(ndb, bool)
-            delta_live[:nd] = self._delta_live
-            delta_gids = np.zeros(ndb, np.int32)
-            delta_gids[:nd] = self._delta_ids.astype(np.int32)
-        else:
-            W = max(1, (self.L + 31) // 32)
-            delta_vert = jnp.zeros((self.b, W, 0), jnp.uint32)
-            delta_live = np.zeros(0, bool)
-            delta_gids = np.zeros(0, np.int32)
+        with _obs_span("delta_planes", cat="device", rows=nd):
+            if nd:
+                delta_vert = self._delta_planes()
+                ndb = delta_vert.shape[-1]
+                delta_live = np.zeros(ndb, bool)
+                delta_live[:nd] = self._delta_live
+                delta_gids = np.zeros(ndb, np.int32)
+                delta_gids[:nd] = self._delta_ids.astype(np.int32)
+            else:
+                W = max(1, (self.L + 31) // 32)
+                delta_vert = jnp.zeros((self.b, W, 0), jnp.uint32)
+                delta_live = np.zeros(0, bool)
+                delta_gids = np.zeros(0, np.int32)
         staged = None
         if self.backend == "bst":
             if self.layout == "suffix":
-                store = self._refresh_store()
-                # copy-ahead: upload every cold block's staging slab
-                # ONCE per query, before the rung loop — the async
-                # device_put overlaps the first rung's traversal, and
-                # ladder retries reuse the same slabs
-                staged = store.stage()
+                with _obs_span("store_refresh", cat="device"):
+                    store = self._refresh_store()
+                    # copy-ahead: upload every cold block's staging slab
+                    # ONCE per query, before the rung loop — the async
+                    # device_put overlaps the first rung's traversal, and
+                    # ladder retries reuse the same slabs
+                    staged = store.stage()
                 seg_arg = store.live
             else:
                 seg_arg = self._refresh_arena().live
@@ -1854,18 +2001,22 @@ class SegmentedIndex:
             # span covers build/fetch + dispatch + the steering-scalar
             # readback (the sync point where device time surfaces)
             with _obs_span("rung_dispatch", cat="device", kind=kind,
-                           tau=tau, rung=rung):
-                fn = self._fused_fn(kind, tau, rung, kk)
+                           tau=tau, rung=rung) as sp:
+                with _obs_span("rung_program", cat="device"):
+                    fn = self._fused_fn(kind, tau, rung, kk)
                 _dispatch("fused")
-                if staged is not None:
-                    out = fn(jnp.asarray(qs_p), seg_arg, staged,
-                             delta_vert, jnp.asarray(delta_live),
+                with _obs_span("rung_launch", cat="device"):
+                    args = (jnp.asarray(qs_p), seg_arg)
+                    if staged is not None:
+                        args += (staged,)
+                    args += (delta_vert, jnp.asarray(delta_live),
                              jnp.asarray(delta_gids))
-                else:
-                    out = fn(jnp.asarray(qs_p), seg_arg, delta_vert,
-                             jnp.asarray(delta_live),
-                             jnp.asarray(delta_gids))
-                done = int(out[-1]) == 0 or self._fused_saturated(rung)
+                    out = fn(*args)
+                with _obs_span("rung_wait", cat="device"):
+                    done = (int(out[-1]) == 0
+                            or self._fused_saturated(rung))
+                if sp is not None:
+                    sp.args["program"] = _scope_label(fn, args)
             if done:
                 return out
             rung += 1

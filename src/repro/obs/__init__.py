@@ -12,12 +12,12 @@ from .explain import QueryExplain, RungExplain
 from .prom import (DEFAULT_LATENCY_BUCKETS_S, Histogram, format_value,
                    parse_exposition)
 from .slowlog import SlowQueryLog
-from .trace import (Span, Tracer, attach, chrome_trace, current, span,
-                    span_to_dict, write_chrome)
+from .trace import (Span, Tracer, attach, chrome_trace, compile_stats,
+                    current, span, span_to_dict, span_totals, write_chrome)
 
 __all__ = [
-    "Span", "Tracer", "attach", "chrome_trace", "current", "span",
-    "span_to_dict", "write_chrome",
+    "Span", "Tracer", "attach", "chrome_trace", "compile_stats", "current",
+    "span", "span_to_dict", "span_totals", "write_chrome",
     "QueryExplain", "RungExplain",
     "Histogram", "DEFAULT_LATENCY_BUCKETS_S", "format_value",
     "parse_exposition",

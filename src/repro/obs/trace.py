@@ -21,8 +21,19 @@ model:
   * with nothing attached, ``span()`` returns a shared no-op context
     manager after ONE thread-local read — the disabled cost is a dict
     build and a ``getattr``, and no device work ever happens either way
-    (spans are host-side wall-clock timers only; the zero-dispatch
-    invariant is spy-tested in ``tests/test_obs.py``).
+    (the zero-dispatch invariant is spy-tested in ``tests/test_obs.py``);
+  * a live span (``span()`` under an attached context, and the attached
+    batch itself) also opens ``jax.profiler.TraceAnnotation("obs.<name>")``,
+    so a profiler trace holds the worker's span stack on the same clock
+    as the device ops.  Spans built after the fact (``request``,
+    ``queue_wait``, ``compile``) stay in the ring only.
+
+Two process-wide tallies sit beside the ring: ``span_totals()`` (count
+and seconds per span name of every span recorded since process start —
+it survives ``Tracer.clear()``) and ``compile_stats()`` (every backend
+compile, from one ``jax.monitoring`` listener, attached or not).  A
+compile on a thread with an attached span also lands in the tree as a
+``compile`` child, back-dated by its duration.
 
 Export is Chrome trace-event JSON (``chrome_trace`` /
 ``Tracer.write_chrome``): "X" complete events in microseconds, one
@@ -35,12 +46,23 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "attach", "chrome_trace", "current", "span",
-           "span_to_dict", "write_chrome"]
+from jax import monitoring
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "attach", "chrome_trace", "compile_stats",
+           "current", "span", "span_to_dict", "span_totals", "write_chrome"]
 
 _TLS = threading.local()
+
+# process-wide tallies (span_totals / compile_stats); written from every
+# scheduler worker thread, so read-modify-write goes under the lock
+_TALLY_LOCK = threading.Lock()
+_SPAN_TOTALS: Dict[str, List[float]] = {}      # name -> [count, seconds]
+_COMPILES: List[Tuple[float, float]] = []      # (perf_counter at end, s)
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class Span:
@@ -115,21 +137,41 @@ class _NullCtx:
 _NULL = _NullCtx()
 
 
+def _add_total(name: str, seconds: float) -> None:
+    with _TALLY_LOCK:
+        tot = _SPAN_TOTALS.setdefault(name, [0, 0.0])
+        tot[0] += 1
+        tot[1] += seconds
+
+
+def span_totals() -> Dict[str, Tuple[int, float]]:
+    """``{name: (count, seconds)}`` of every span closed under an
+    attached context since process start (``compile`` children
+    included).  Unlike the ring it is never cleared, so work done before
+    a ``Tracer.clear()`` — a bulk load's trie builds — stays readable."""
+    with _TALLY_LOCK:
+        return {k: (int(n), s) for k, (n, s) in _SPAN_TOTALS.items()}
+
+
 class _SpanCtx:
-    __slots__ = ("parent", "sp")
+    __slots__ = ("parent", "sp", "ann")
 
     def __init__(self, parent: Span, name: str, cat: str, args: dict):
         self.parent = parent
         self.sp = Span(name, cat=cat, args=args)
+        self.ann = TraceAnnotation("obs." + name)
 
     def __enter__(self) -> Span:
         self.parent.children.append(self.sp)
         _TLS.cur = self.sp
+        self.ann.__enter__()
         return self.sp
 
     def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
         self.sp.dur = time.perf_counter() - self.sp.ts
         _TLS.cur = self.parent
+        _add_total(self.sp.name, self.sp.dur)
         return False
 
 
@@ -144,18 +186,24 @@ def span(name: str, cat: str = "span", **args):
 
 
 class _AttachCtx:
-    __slots__ = ("root", "prev")
+    __slots__ = ("root", "prev", "ann")
 
     def __init__(self, root: Optional[Span]):
         self.root = root
         self.prev = None
+        self.ann = (None if root is None
+                    else TraceAnnotation("obs." + root.name))
 
     def __enter__(self):
         self.prev = getattr(_TLS, "cur", None)
         _TLS.cur = self.root
+        if self.ann is not None:
+            self.ann.__enter__()
         return self.root
 
     def __exit__(self, *exc):
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         _TLS.cur = self.prev
         return False
 
@@ -165,6 +213,35 @@ def attach(root: Optional[Span]) -> _AttachCtx:
     scheduler attaches the batch span around execution; ``None``
     detaches — a no-op region)."""
     return _AttachCtx(root)
+
+
+# -- compiles ------------------------------------------------------------
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    """The ``jax.monitoring`` listener: JAX calls it on the compiling
+    thread once the backend compile has finished."""
+    if event != COMPILE_EVENT:
+        return
+    end = time.perf_counter()
+    with _TALLY_LOCK:
+        _COMPILES.append((end, seconds))
+    parent = getattr(_TLS, "cur", None)
+    if parent is not None:
+        parent.children.append(Span("compile", cat="compile",
+                                    ts=end - seconds, dur=seconds))
+        _add_total("compile", seconds)
+
+
+monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_stats(until: Optional[float] = None) -> Dict[str, float]:
+    """``{"compiles", "compile_s"}``: backend compiles of this process
+    since start — with ``until`` (a ``time.perf_counter`` reading), only
+    those that had finished by then."""
+    with _TALLY_LOCK:
+        secs = [s for t, s in _COMPILES if until is None or t <= until]
+    return {"compiles": len(secs), "compile_s": float(sum(secs))}
 
 
 # -- ring buffer ---------------------------------------------------------

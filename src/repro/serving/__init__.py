@@ -32,7 +32,7 @@ backstop; every ``submit_*`` then accepts ``deadline_ms=`` /
 
 from .batching import bucket_m, bucket_table, pad_to_bucket
 from .collections import Collection, CollectionConfig, CollectionRegistry
-from .metrics import LatencyWindow, ServingMetrics
+from .metrics import ServingMetrics
 from .overload import (AdmissionConfig, AdmissionController, BreakerConfig,
                        CircuitBreaker, DeadlineExceeded, DegradePolicy,
                        SlowDispatchInjector)
@@ -42,7 +42,7 @@ from .scheduler import (OverloadError, Scheduler, SchedulerConfig,
 __all__ = [
     "bucket_m", "bucket_table", "pad_to_bucket",
     "Collection", "CollectionConfig", "CollectionRegistry",
-    "LatencyWindow", "ServingMetrics",
+    "ServingMetrics",
     "AdmissionConfig", "AdmissionController", "BreakerConfig",
     "CircuitBreaker", "DeadlineExceeded", "DegradePolicy",
     "SlowDispatchInjector",

@@ -1,19 +1,19 @@
-"""Serving metrics: latency percentiles + histograms, throughput
-counters, batching efficiency, and a ``/stats`` text dump in real
-Prometheus exposition format (DESIGN.md §5, §11).
+"""Serving metrics: latency histograms, throughput counters, batching
+efficiency, and a ``/stats`` text dump in real Prometheus exposition
+format (DESIGN.md §5, §11).
 
 One ``ServingMetrics`` instance is shared by a scheduler and all its
-collections.  Latencies are kept in bounded per-op ring buffers (recent
-window, not full history) so a long-lived server's percentile cost stays
-O(window), plus fixed-bucket cumulative ``Histogram``s (full history —
-what a scraper rates over).  All mutators take an internal lock — the
-scheduler records from its worker threads while ``snapshot()`` /
-``render_text()`` may be called from any thread.
+collections.  Latencies go into fixed-bucket cumulative ``Histogram``s
+(full history — what a scraper rates over; quantiles come from the
+buckets).  All mutators take an internal lock — the scheduler records
+from its worker threads while ``snapshot()`` / ``render_text()`` may be
+called from any thread.
 
-Cache / dispatch / tier efficiency come from *process-level* counters
-(``repro.core.search.searcher_cache_info``,
+Cache / dispatch / tier / compile efficiency come from *process-level*
+counters (``repro.core.search.searcher_cache_info``,
 ``repro.core.segments.dispatch_stats``,
-``repro.core.column_store.tier_stats``).  Those globals are shared by
+``repro.core.column_store.tier_stats``, ``repro.obs.compile_stats``).
+Those globals are shared by
 every index in the process, so each ``ServingMetrics`` snapshots them at
 construction and reports **deltas since its own start** — two schedulers
 (or a test running after a warm-up) no longer see each other's traffic.
@@ -26,46 +26,20 @@ import collections
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from ..core.column_store import tier_stats
 from ..core.search import searcher_cache_info
 from ..core.segments import dispatch_stats
 from ..obs.prom import (DEFAULT_LATENCY_BUCKETS_S, Histogram, format_value,
                         render_family)
+from ..obs.trace import compile_stats
 
-__all__ = ["LatencyWindow", "ServingMetrics"]
+__all__ = ["ServingMetrics"]
 
-
-class LatencyWindow:
-    """Bounded ring buffer of recent latency samples (seconds)."""
-
-    def __init__(self, window: int = 2048):
-        self.samples = collections.deque(maxlen=window)
-        self.count = 0          # total ever recorded (not windowed)
-        self.total = 0.0        # total seconds ever recorded
-
-    def add(self, seconds: float) -> None:
-        self.samples.append(seconds)
-        self.count += 1
-        self.total += seconds
-
-    def percentile(self, p: float) -> float:
-        if not self.samples:
-            return 0.0
-        return float(np.percentile(np.asarray(self.samples), p))
-
-    def summary(self) -> Dict[str, float]:
-        return {
-            "count": self.count,
-            "mean_ms": (self.total / self.count * 1e3) if self.count else 0.0,
-            "p50_ms": self.percentile(50) * 1e3,
-            "p99_ms": self.percentile(99) * 1e3,
-        }
+_LATENCY_KINDS = ("latency", "exec_latency", "queue_latency")
 
 
 class ServingMetrics:
-    """Counters + latency windows/histograms for one scheduler.
+    """Counters + latency histograms for one scheduler.
 
     * ``record_latency(op, s)`` — end-to-end (enqueue -> complete).
     * ``record_exec(op, s)``    — device dispatch only.
@@ -82,14 +56,9 @@ class ServingMetrics:
       families in the exposition.
     """
 
-    def __init__(self, window: int = 2048,
-                 buckets: Sequence[float] = DEFAULT_LATENCY_BUCKETS_S):
+    def __init__(self, buckets: Sequence[float] = DEFAULT_LATENCY_BUCKETS_S):
         self._lock = threading.Lock()
-        self._window = window
         self._buckets = tuple(buckets)
-        self.latency: Dict[str, LatencyWindow] = {}
-        self.exec_latency: Dict[str, LatencyWindow] = {}
-        self.queue_latency: Dict[str, LatencyWindow] = {}
         self._hists: Dict[Tuple[str, str], Histogram] = {}
         self.counters: Dict[str, int] = collections.defaultdict(int)
         self.gauges: Dict[str, float] = {}
@@ -98,21 +67,16 @@ class ServingMetrics:
         self.rebaseline()
 
     def rebaseline(self) -> None:
-        """Re-zero the process-global cache/dispatch/tier deltas: every
-        later ``snapshot()`` reports activity since this call (called
-        once at construction — i.e. scheduler start)."""
+        """Re-zero the process-global cache/dispatch/tier/compile deltas:
+        every later ``snapshot()`` reports activity since this call
+        (called once at construction — i.e. scheduler start)."""
         with self._lock:
             self._cache0 = searcher_cache_info()
             self._disp0 = dispatch_stats()
             self._tier0 = tier_stats()
+            self._comp0 = compile_stats()
 
     # -- recording -------------------------------------------------------
-
-    def _win(self, table: Dict[str, LatencyWindow], op: str) -> LatencyWindow:
-        win = table.get(op)
-        if win is None:
-            win = table[op] = LatencyWindow(self._window)
-        return win
 
     def _hist(self, kind: str, op: str) -> Histogram:
         h = self._hists.get((kind, op))
@@ -122,17 +86,14 @@ class ServingMetrics:
 
     def record_latency(self, op: str, seconds: float) -> None:
         with self._lock:
-            self._win(self.latency, op).add(seconds)
             self._hist("latency", op).observe(seconds)
 
     def record_exec(self, op: str, seconds: float) -> None:
         with self._lock:
-            self._win(self.exec_latency, op).add(seconds)
             self._hist("exec_latency", op).observe(seconds)
 
     def record_queue(self, op: str, seconds: float) -> None:
         with self._lock:
-            self._win(self.queue_latency, op).add(seconds)
             self._hist("queue_latency", op).observe(seconds)
 
     def record_batch(self, op: str, size: int, bucket: int) -> None:
@@ -161,22 +122,25 @@ class ServingMetrics:
 
     def snapshot(self) -> Dict[str, object]:
         """One coherent dict of everything: counters, per-op latency
-        summaries (count / mean / p50 / p99 ms), batch fill, and the
-        compiled-searcher cache / dispatch / tier counters **as deltas
-        since this instance's baseline** (``size`` stays absolute — it
-        is an occupancy gauge, not a flow)."""
+        totals (count / mean ms, from the histograms), batch fill, and
+        the compiled-searcher cache / dispatch / tier / compile counters
+        **as deltas since this instance's baseline** (``size`` stays
+        absolute — it is an occupancy gauge, not a flow)."""
         with self._lock:
             out: Dict[str, object] = {
                 "counters": dict(self.counters),
                 "gauges": dict(self.gauges),
-                "latency": {op: w.summary() for op, w in self.latency.items()},
-                "exec_latency": {op: w.summary()
-                                 for op, w in self.exec_latency.items()},
-                "queue_latency": {op: w.summary()
-                                  for op, w in self.queue_latency.items()},
                 "batch_fill_ratio": self.batch_fill_ratio(),
             }
+            for kind in _LATENCY_KINDS:
+                out[kind] = {
+                    op: {"count": h.count,
+                         "mean_ms": h.total / h.count * 1e3 if h.count
+                         else 0.0}
+                    for (k, op), h in sorted(self._hists.items())
+                    if k == kind}
             cache0, disp0, tier0 = self._cache0, self._disp0, self._tier0
+            comp0 = self._comp0
         cache_now = searcher_cache_info()
         cache = {k: cache_now[k] - cache0.get(k, 0)
                  for k in cache_now if k != "size"}
@@ -188,6 +152,8 @@ class ServingMetrics:
                                   for k, v in dispatch_stats().items()}
         out["tier"] = {k: v - tier0.get(k, 0)
                        for k, v in tier_stats().items()}
+        out["compile"] = {k: v - comp0[k]
+                          for k, v in compile_stats().items()}
         return out
 
     def render_text(self, extra: Optional[Dict[str, object]] = None) -> str:
@@ -223,17 +189,6 @@ class ServingMetrics:
         for fam in sorted(fams):
             emit(fam, "counter", "Scheduler request counter.", fams[fam])
 
-        for table, label in ((snap["latency"], "latency"),
-                             (snap["exec_latency"], "exec_latency"),
-                             (snap["queue_latency"], "queue_latency")):
-            for stat in ("p50_ms", "p99_ms", "mean_ms"):
-                fam = f"serving_{label}_{stat}"
-                lines = [f'{fam}{{op="{op}"}} {format_value(s[stat])}'
-                         for op, s in sorted(table.items())]
-                if lines:
-                    emit(fam, "gauge",
-                         f"Windowed {label} {stat} per op.", lines)
-
         emit("serving_batch_fill_ratio", "gauge",
              "Real queries / dispatched bucket rows.",
              ["serving_batch_fill_ratio "
@@ -255,6 +210,13 @@ class ServingMetrics:
             emit(f"device_dispatch_{k}", "counter",
                  "Device launches (delta since scheduler start).",
                  [f"device_dispatch_{k} {format_value(v)}"])
+        comp = snap["compile"]
+        emit("compiles_total", "counter",
+             "Backend compiles (delta since scheduler start).",
+             [f"compiles_total {format_value(comp['compiles'])}"])
+        emit("compile_seconds_total", "counter",
+             "Backend compile seconds (delta since scheduler start).",
+             [f"compile_seconds_total {format_value(comp['compile_s'])}"])
         for k, v in sorted(snap["tier"].items()):
             emit(f"tier_{k}", "counter",
                  "Column-store tier movement (delta since scheduler start).",

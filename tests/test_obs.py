@@ -1,23 +1,32 @@
 """Observability (DESIGN.md §11): ``explain=True`` must be bit-identical
 to the plain call on every backend; tracing disabled must add zero
 device dispatches (the instrumentation points are shared no-ops); the
-trace ring is bounded; the Chrome export loads and nests; the metrics
-exposition round-trips through a strict Prometheus parser; and a fresh
+trace ring is bounded; the Chrome export loads and nests; the fused rung
+program's named stages change HLO metadata only; the metrics exposition
+round-trips through a strict Prometheus parser; and a fresh
 ``ServingMetrics`` never sees another instance's process-global traffic.
 """
 
+import contextlib
 import json
 import os
+import re
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
+import jax
+
+from repro.core import segments
 from repro.core.segments import (SegmentedIndex, ShardedSegmentedIndex,
                                  dispatch_stats)
 from repro.core.hamming import pack_sets
 from repro.obs import (QueryExplain, SlowQueryLog, Span, Tracer, attach,
-                       chrome_trace, format_value, parse_exposition, span)
+                       chrome_trace, compile_stats, format_value,
+                       parse_exposition, span, span_totals)
 from repro.obs.prom import Histogram
 from repro.obs.trace import _NULL, current
 from repro.serving import (CollectionConfig, Scheduler, SchedulerConfig,
@@ -143,12 +152,205 @@ def test_tracing_disabled_zero_extra_dispatches():
     with attach(root):
         traced = idx.topk(QUERY, k=4)
     d_traced = {k: v - d1[k] for k, v in dispatch_stats().items()}
-    # spans are host wall-clock only: the device ledger is identical
+    # spans are host timers and profiler annotations: the device ledger
+    # is identical
     assert d_traced == d_plain
     np.testing.assert_array_equal(np.asarray(plain.ids),
                                   np.asarray(traced.ids))
-    assert root.find("rung_dispatch") is not None
-    assert root.find("topk_readback") is not None
+    for name in ("delta_planes", "store_refresh", "rung_dispatch",
+                 "rung_program", "rung_launch", "rung_wait",
+                 "topk_readback"):
+        assert root.find(name) is not None, name
+
+
+def _suffix_index():
+    """A suffix-layout index with two sealed segments and delta rows."""
+    idx = SegmentedIndex(L=16, b=B, delta_cap=256, layout="suffix",
+                         auto_merge=False)
+    rows = np.random.default_rng(3).integers(0, 1 << B, size=(650, 16),
+                                             dtype=np.uint8)
+    for lo in range(0, len(rows), 100):
+        idx.insert(rows[lo:lo + 100])
+    assert len(idx.segments) == 2 and idx.stats()["delta_rows"] == 50
+    return idx, rows
+
+
+def _walk(sp):
+    yield sp
+    for ch in sp.children:
+        yield from _walk(ch)
+
+
+def test_traced_suffix_index_bit_identical_and_dispatch_nests():
+    idx, rows = _suffix_index()
+    qs = rows[:3]
+    plain = idx.topk_batch(qs, 5)
+    root = Span("request")
+    with attach(root):
+        traced = idx.topk_batch(qs, 5)
+    for a, b in ((plain.ids, traced.ids), (plain.dists, traced.dists)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (plain.tau, plain.overflow) == (traced.tau, traced.overflow)
+    rungs = [sp for sp in _walk(root) if sp.name == "rung_dispatch"]
+    assert rungs
+    tables = segments.fused_scope_tables()
+    for sp in rungs:
+        kids = [ch.name for ch in sp.children if ch.name != "compile"]
+        assert kids == ["rung_program", "rung_launch", "rung_wait"]
+        assert sp.args["program"] in tables
+        for ch in sp.children:
+            assert sp.ts <= ch.ts and ch.ts + ch.dur <= sp.ts + sp.dur
+
+
+def test_trie_build_nests_under_seal_and_merge():
+    before = span_totals().get("trie_build", (0, 0.0))
+    idx = SegmentedIndex(L=L, b=B, delta_cap=64)
+    root = Span("execute")
+    with attach(root):
+        idx.insert(SKETCHES[:64])
+        idx.insert(SKETCHES[64:128])       # the second seal merges
+    assert idx.counters == {**idx.counters, "flushes": 2, "merges": 1}
+    seals = [sp for sp in _walk(root) if sp.name == "seal"]
+    merges = [sp for sp in _walk(root) if sp.name == "merge"]
+    assert [sp.args["rows"] for sp in seals] == [64, 64]
+    assert [sp.args["rows"] for sp in merges] == [128]
+    for sp in seals + merges:
+        assert [ch.name for ch in sp.children
+                if ch.name != "compile"] == ["trie_build", "pack_vertical"]
+    builds = [sp for sp in _walk(root) if sp.name == "trie_build"]
+    assert len(builds) == 3
+    after = span_totals()["trie_build"]
+    assert after[0] - before[0] == 3
+    assert after[1] - before[1] == pytest.approx(
+        sum(sp.dur for sp in builds))
+
+
+def test_span_totals_exact_under_threads():
+    """Scheduler workers close spans concurrently: the process-wide
+    tally loses no update."""
+    workers, per = 16, 400
+    before = span_totals().get("tally_stress", (0, 0.0))[0]
+
+    def work():
+        with attach(Span("batch")):
+            for _ in range(per):
+                with span("tally_stress"):
+                    pass
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in threads)
+    assert span_totals()["tally_stress"][0] - before == workers * per
+
+
+def test_cold_rung_adds_one_compile_span():
+    idx = _filled(SegmentedIndex(L=L, b=B, delta_cap=64))
+    c0 = compile_stats()
+    t0 = time.perf_counter()
+    root = Span("request")
+    with attach(root):
+        idx.topk(QUERY, k=4, tau0=3)          # first call: compiles
+    c1 = compile_stats()
+    compiles = [sp for sp in _walk(root) if sp.name == "compile"]
+    assert c1["compiles"] - c0["compiles"] == len(compiles) >= 1
+    assert c1["compile_s"] - c0["compile_s"] == pytest.approx(
+        sum(sp.dur for sp in compiles))
+    launches = [sp for sp in _walk(root) if sp.name == "rung_launch"]
+    assert launches
+    for launch in launches:
+        assert [ch.name for ch in launch.children] == ["compile"]
+    assert compile_stats(until=t0) == c0
+    root = Span("request")
+    with attach(root):
+        idx.topk(QUERY, k=4, tau0=3)          # warm: nothing compiles
+    assert not [sp for sp in _walk(root) if sp.name == "compile"]
+    assert compile_stats()["compiles"] == c1["compiles"]
+
+
+def _module_texts(monkeypatch, scoped: bool):
+    """The optimized HLO of every fused program variant one top-k batch
+    runs, as the scope table recorder sees it."""
+    texts = []
+    real = segments.hlo_scopes
+
+    def spy(text):
+        texts.append(text)
+        return real(text)
+    monkeypatch.setattr(segments, "hlo_scopes", spy)
+    if not scoped:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+    idx, rows = _suffix_index()
+    with attach(Span("request")):
+        idx.topk_batch(rows[:2], 5, tau0=2)
+    monkeypatch.undo()
+    return texts
+
+
+def _strip_metadata(text):
+    text = re.sub(r', metadata=\{(?:[^{}"]|"[^"]*")*\}', "", text)
+    return [ln for ln in text.splitlines()
+            if ln.lstrip().startswith(("%", "ROOT", "ENTRY", "}"))]
+
+
+@pytest.fixture
+def no_persistent_compile_cache():
+    """The persistent cache keys a program without its debug info, so a
+    hit would hand the unscoped program the scoped one's metadata."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def test_rung_scopes_change_hlo_metadata_only(monkeypatch,
+                                              no_persistent_compile_cache):
+    scoped = _module_texts(monkeypatch, True)
+    plain = _module_texts(monkeypatch, False)
+    assert len(scoped) == len(plain) >= 1
+    for a, b in zip(scoped, plain):
+        assert _strip_metadata(a) == _strip_metadata(b)
+        assert not set(segments.RUNG_SCOPES) & set(
+            segments.hlo_scopes(b).values())
+    for name in segments.RUNG_SCOPES:
+        assert f"/{name}/" in scoped[0], name
+    assert set(segments.hlo_scopes(scoped[0]).values()) == set(
+        segments.RUNG_SCOPES)
+
+
+def test_hlo_scopes_by_hand():
+    text = "\n".join([
+        "HloModule jit_run, entry_computation_layout={()->s32[]}",
+        "",
+        "%fused_computation.1 (param_0: s32[4]) -> s32[4] {",
+        "  %param_0 = s32[4]{0} parameter(0)",
+        '  ROOT %add.1 = s32[4]{0} add(%param_0, %param_0), '
+        'metadata={op_name="jit(run)/rung.select/add" stack_frame_id=2}',
+        "}",
+        "",
+        "ENTRY %main.3 (p: s32[4]) -> s32[4] {",
+        "  %p = s32[4]{0} parameter(0)",
+        '  %gather.2 = s32[4]{0} gather(%p, %p), '
+        'metadata={op_name="jit(run)/rung.traverse/jit(_take)/gather"}',
+        "  %pad.5 = s32[4]{0} pad(%gather.2, %p), padding=0_0",
+        "  ROOT %fusion.7 = s32[4]{0} fusion(%pad.5), kind=kLoop, "
+        "calls=%fused_computation.1",
+        "}"])
+    # fusion.7: its callee's root; pad.5 (made by the compiler): its user
+    assert segments.hlo_scopes(text) == {"add.1": "rung.select",
+                                         "gather.2": "rung.traverse",
+                                         "pad.5": "rung.select",
+                                         "fusion.7": "rung.select"}
 
 
 def test_tracer_ring_bounded():

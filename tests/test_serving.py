@@ -279,17 +279,22 @@ def test_metrics_snapshot_and_text_dump():
     snap = sched.stats()
     assert snap["counters"]["requests_total:topk"] == 3
     assert snap["latency"]["topk"]["count"] == 3
-    assert snap["latency"]["topk"]["p99_ms"] >= \
-        snap["latency"]["topk"]["p50_ms"]
+    assert snap["latency"]["topk"]["mean_ms"] > 0
     assert snap["queue_depth"]["c"] == 0
     assert snap["collections"]["c"]["n_live"] == 16
+    assert snap["compile"]["compiles"] >= 0
     text = sched.render_stats()
     for needle in ('serving_requests_total{op="topk"} 3',
-                   'serving_latency_p99_ms{op="topk"}',
+                   'serving_latency_seconds_count{op="topk"} 3',
+                   'serving_latency_seconds_bucket{op="topk",le="+Inf"} 3',
+                   'serving_queue_latency_seconds_count{op="topk"} 3',
                    'index_n_live{collection="c"} 16',
                    "serving_batch_fill_ratio",
-                   "searcher_cache_traces"):
+                   "searcher_cache_traces",
+                   "compiles_total", "compile_seconds_total"):
         assert needle in text, needle
+    # the windowed percentile gauges are gone: the histograms carry them
+    assert "_p99_ms" not in text and "_p50_ms" not in text
 
 
 def test_overload_error_carries_context_and_per_op_counter():
