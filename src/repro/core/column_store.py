@@ -5,7 +5,7 @@ The PR-5 arena (`segments._ColumnArena`) keeps one *full-length*
 (b, W, R) verify column per sealed row device-resident.  That is
 redundant: the fused program's traversal already computes the exact
 prefix distance down to every segment's collapse depth ℓ_s, and the
-verify kernel receives it through the gathered root base plane — so the
+verify kernel receives it through the root base plane — so the
 columns only need the **suffix** below ℓ_s.  This module owns that
 observation end to end, split into two layers:
 
@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hamming import n_words, pack_suffix_words, pack_vertical
+from ..kernels.hamming_kernel import DEFAULT_BLOCK_N, plan_windows
 from ..obs.trace import span as _obs_span
 
 WORD_BYTES = 4
@@ -104,13 +105,16 @@ class _Block:
     ``cols_hot`` (device) and ``cols_cold`` (host) are mutually
     exclusive — exactly one is set, per the block's ``tier``.  Packed
     geometry stores (n,) uint32 words, plane geometry (b, W_sfx, n)
-    uint32.  ``base_idx`` (host, immutable once appended) is the
-    segment-offset lane into the global root base plane."""
+    uint32.  ``roots`` (host, immutable once appended) is each row's ℓ_s
+    root within its own segment — rows are in trie order, so it rises
+    in steps of 0 or 1 from 0 to ``t_root`` - 1; the plan adds the
+    segment's offset in the base plane."""
 
     serial: int
     n: int
     geom: SuffixGeometry
-    base_idx: np.ndarray
+    roots: np.ndarray
+    t_root: int
     cols_hot: Optional[jnp.ndarray] = None
     cols_cold: Optional[np.ndarray] = None
     last_used: int = 0
@@ -144,11 +148,17 @@ class _Group(NamedTuple):
     """One geometry group of the current plan: the per-rung program runs
     one verify kernel per group (inside the single fused dispatch).
     ``perm`` maps the group's column order (hot blocks in stack order,
-    then cold blocks in stack order) back to global stack positions."""
+    then cold blocks in stack order) back to global stack positions.
+    The base plane lays the segments' roots out in the same group-major
+    order, so ``base_idx`` rises in steps of 0 or 1 over the whole group
+    and ``windows`` (``plan_windows``: start, steps) holds each column
+    block's window of it; ``window_roots`` the roots they span."""
 
     geom: SuffixGeometry
     cols_hot: Optional[jnp.ndarray]   # concatenated hot columns (device)
     base_idx: jnp.ndarray             # (n_group,) int32 device lane
+    windows: Tuple[jnp.ndarray, jnp.ndarray]  # (n_blocks,) int32 each
+    window_roots: int
     perm: np.ndarray                  # (n_group,) int64 stack positions
     cold_blocks: Tuple[int, ...]      # indexes into store.blocks
     cold_bytes: int
@@ -179,13 +189,12 @@ class ColumnStore:
         self.gids: jnp.ndarray = jnp.zeros((0,), jnp.int32)
         self.col_ids = np.zeros((0,), np.int64)
         self.col_off: Dict[int, int] = {}
-        self.root_off: Dict[int, int] = {}
-        self.t_root_total = 0
         self.gen = 0                   # bumped on every tier flip
         self._tick = 0                 # LRU clock
         self._plan: Optional[Tuple[_Group, ...]] = None
         self._order: Tuple[Optional[jnp.ndarray], Optional[jnp.ndarray]] = (
             None, None)
+        self._root_order: Tuple[int, ...] = ()
 
     @property
     def n_cols(self) -> int:
@@ -195,8 +204,8 @@ class ColumnStore:
 
     def append_segment(self, seg) -> None:
         """Append one sealed segment's block: suffix columns sliced below
-        its own ℓ_s, packed per :func:`geometry_for`, plus the shared
-        base-offset/gid/liveness/id lanes.  New blocks start hot; the
+        its own ℓ_s, packed per :func:`geometry_for`, its root lane, plus
+        the shared gid/liveness/id lanes.  New blocks start hot; the
         budget is enforced at :meth:`seal`."""
         ls = int(seg.index.ls)
         geom = geometry_for(self.L, self.b, ls)
@@ -206,10 +215,8 @@ class ColumnStore:
         else:
             cols = np.ascontiguousarray(
                 np.transpose(pack_vertical(sfx, self.b), (1, 2, 0)))
-        root0 = 1 + self.t_root_total        # slot 0: delta's trivial base
         leaf_root = np.asarray(seg.index.tail.leaf_root)
-        id_leaf = np.asarray(seg.index.id_leaf)
-        base_idx = (root0 + leaf_root[id_leaf]).astype(np.int32)
+        roots = leaf_root[np.asarray(seg.index.id_leaf)].astype(np.int32)
         pays_hot = None
         pay_words = 0
         if self.payload_words is not None:
@@ -221,12 +228,10 @@ class ColumnStore:
                 seg.payloads.T.astype(np.uint32)))       # (Wp, n)
         self._tick += 1
         self.blocks.append(_Block(
-            serial=seg.serial, n=seg.n, geom=geom, base_idx=base_idx,
-            cols_hot=jnp.asarray(cols), last_used=self._tick,
-            pays_hot=pays_hot, pay_words=pay_words))
+            serial=seg.serial, n=seg.n, geom=geom, roots=roots,
+            t_root=int(seg.index.tail.t_root), cols_hot=jnp.asarray(cols),
+            last_used=self._tick, pays_hot=pays_hot, pay_words=pay_words))
         self.col_off[seg.serial] = self.n_cols
-        self.root_off[seg.serial] = root0
-        self.t_root_total += int(seg.index.tail.t_root)
         self.live = jnp.concatenate([self.live, jnp.asarray(seg.live)])
         self.gids = jnp.concatenate(
             [self.gids, jnp.asarray(seg.ids.astype(np.int32))])
@@ -289,15 +294,20 @@ class ColumnStore:
     def plan(self) -> Tuple[_Group, ...]:
         """Group blocks by geometry (one kernel call per group inside the
         fused program): hot columns pre-concatenated device-side, cold
-        blocks listed for :meth:`stage`, base-offset lanes as one device
-        constant, and the stack-position permutation that restores the
-        global column order.  Cached until the stack or a tier changes."""
+        blocks listed for :meth:`stage`, base-offset lanes and their
+        window plans as device constants, and the stack-position
+        permutation that restores the global column order.  Roots are
+        laid out in the plan's block order (:meth:`root_order`), so each
+        group's lane rises in steps of 0 or 1 — ``plan_windows`` refuses
+        it otherwise.  Cached until the stack or a tier changes."""
         if self._plan is not None:
             return self._plan
         order: Dict[SuffixGeometry, List[int]] = {}
         for bi, blk in enumerate(self.blocks):
             order.setdefault(blk.geom, []).append(bi)
         groups: List[_Group] = []
+        root_order: List[int] = []
+        root0 = 0
         for geom, idxs in order.items():
             hot = [i for i in idxs if self.blocks[i].tier == TIER_HOT]
             cold = [i for i in idxs if self.blocks[i].tier == TIER_COLD]
@@ -305,8 +315,13 @@ class ColumnStore:
                 self.col_off[self.blocks[i].serial]
                 + np.arange(self.blocks[i].n)
                 for i in hot + cold]).astype(np.int64)
-            base_idx = np.concatenate(
-                [self.blocks[i].base_idx for i in hot + cold])
+            lanes = []
+            for i in hot + cold:
+                lanes.append(root0 + self.blocks[i].roots)
+                root0 += self.blocks[i].t_root
+            root_order += hot + cold
+            base_idx = np.concatenate(lanes).astype(np.int32)
+            windows = plan_windows(base_idx, DEFAULT_BLOCK_N)
             axis = 0 if geom.packed else -1
             cols_hot = (jnp.concatenate(
                 [self.blocks[i].cols_hot for i in hot], axis=axis)
@@ -317,13 +332,17 @@ class ColumnStore:
                     [self.blocks[i].pays_hot for i in hot], axis=-1)
             groups.append(_Group(
                 geom=geom, cols_hot=cols_hot,
-                base_idx=jnp.asarray(base_idx), perm=perm,
+                base_idx=jnp.asarray(base_idx),
+                windows=(jnp.asarray(windows.start),
+                         jnp.asarray(windows.steps)),
+                window_roots=windows.roots, perm=perm,
                 cold_blocks=tuple(cold),
                 cold_bytes=sum(self.blocks[i].col_bytes for i in cold),
                 pays_hot=pays_hot,
                 pay_cold_bytes=sum(self.blocks[i].pay_bytes
                                    for i in cold)))
         self._plan = tuple(groups)
+        self._root_order = tuple(root_order)
         order = np.concatenate([g.perm for g in groups]) if groups else \
             np.zeros((0,), np.int64)
         if np.array_equal(order, np.arange(self.n_cols)):
@@ -332,6 +351,14 @@ class ColumnStore:
             self._order = (jnp.asarray(order.astype(np.int32)),
                            jnp.asarray(np.argsort(order).astype(np.int32)))
         return self._plan
+
+    def root_order(self) -> Tuple[int, ...]:
+        """Block indexes (stack positions) in the order the base plane
+        lays out their roots: the plan's groups, each hot blocks then
+        cold.  The fused program concatenates the segments' root planes
+        in this order."""
+        self.plan()
+        return self._root_order
 
     def order(self) -> Tuple[Optional[jnp.ndarray], Optional[jnp.ndarray]]:
         """Device int32 permutations between the plan's group-major column
@@ -394,7 +421,7 @@ class ColumnStore:
         by = int(self.live.nbytes + self.gids.nbytes)
         by += sum(blk.block_bytes for blk in self.blocks
                   if blk.tier == TIER_HOT)
-        by += sum(blk.base_idx.nbytes for blk in self.blocks)
+        by += sum(blk.roots.nbytes for blk in self.blocks)
         return by
 
     def host_bytes(self) -> int:

@@ -65,9 +65,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops
-from ..kernels.hamming_kernel import DEFAULT_BLOCK_M
+from ..kernels.hamming_kernel import DEFAULT_BLOCK_M, WindowPlan
 from ..kernels.ref import RERANK_METRICS
 from .bst import BIG, build_bst
+from .trie_builder import build_trie_levels
 from .column_store import ColumnStore, tier_stats
 from .cost_model import cost_single, frontier_capacities, tau_for_k
 from .distributed_search import (build_sharded_bst, make_sharded_searcher,
@@ -110,7 +111,12 @@ _SEG_SERIALS = itertools.count()
 # ``topk(rerank=...)`` request, regardless of segment count —
 # DESIGN.md §10).  The serving metrics snapshot exposes these —
 # dispatch accounting replaces per-segment accounting (DESIGN.md §6).
-_DISPATCH_STATS = {"total": 0, "fused": 0, "fanout": 0, "rerank": 0}
+# Per fused launch the windowed arena verify also tallies
+# "windowed_cols" (columns verified through the in-kernel base-window
+# expansion), "windows" (column blocks expanded, per query tile) and
+# "window_roots" (the roots those windows span).
+_DISPATCH_STATS = {"total": 0, "fused": 0, "fanout": 0, "rerank": 0,
+                   "windowed_cols": 0, "windows": 0, "window_roots": 0}
 # the counters are bumped from every scheduler worker thread — guard the
 # read-modify-write (plain ``+=`` on a dict slot is not atomic)
 _DISPATCH_LOCK = threading.Lock()
@@ -122,13 +128,24 @@ def _dispatch(kind: str) -> None:
         _DISPATCH_STATS[kind] += 1
 
 
+def _tally_windows(cols: int, windows: int, roots: int) -> None:
+    with _DISPATCH_LOCK:
+        _DISPATCH_STATS["windowed_cols"] += cols
+        _DISPATCH_STATS["windows"] += windows
+        _DISPATCH_STATS["window_roots"] += roots
+
+
 def dispatch_stats() -> Dict[str, int]:
     """Device-dispatch counters of the segmented query path: ``total``
     host->device program launches, split into ``fused`` (arena path —
     one per τ rung, independent of segment count), ``fanout``
     (per-segment reference path — one per segment per rung), and
     ``rerank`` (exact re-rank pass — one per ``topk(rerank=...)``
-    request, never per segment)."""
+    request, never per segment).  The fused launches' windowed verify
+    adds ``windowed_cols`` (columns whose base came through the
+    in-kernel window expansion), ``windows`` (column blocks expanded,
+    once per query tile) and ``window_roots`` (roots those windows
+    span: ``window_roots / windows`` is the mean roots per window)."""
     with _DISPATCH_LOCK:
         return dict(_DISPATCH_STATS)
 
@@ -137,6 +154,11 @@ def reset_dispatch_stats() -> None:
     with _DISPATCH_LOCK:
         for k in _DISPATCH_STATS:
             _DISPATCH_STATS[k] = 0
+
+
+def next_serial() -> int:
+    """A segment serial never handed out before in this process."""
+    return next(_SEG_SERIALS)
 
 
 def ensure_serial_floor(floor: int) -> None:
@@ -149,6 +171,31 @@ def ensure_serial_floor(floor: int) -> None:
     with _DISPATCH_LOCK:
         cur = next(_SEG_SERIALS)
         _SEG_SERIALS = itertools.count(max(cur, int(floor)))
+
+
+def _rows_of(id_arr: np.ndarray, ids: np.ndarray,
+             sorter: Optional[np.ndarray] = None) -> np.ndarray:
+    """Positions in ``id_arr`` of those ``ids`` it holds; ``sorter``
+    sorts ``id_arr`` (None: it is ascending already)."""
+    if id_arr.size == 0:
+        return np.zeros((0,), np.int64)
+    pos = np.minimum(np.searchsorted(id_arr, ids, sorter=sorter),
+                     id_arr.size - 1)
+    rows = pos if sorter is None else sorter[pos]
+    return rows[id_arr[rows] == ids]
+
+
+def _ties_by_id(order: np.ndarray, leaf: np.ndarray,
+                ids: np.ndarray) -> np.ndarray:
+    """Reorder each run of equal rows (one leaf) of ``order`` by id.
+    Such runs are rare, so only their rows are sorted."""
+    tie = np.flatnonzero(leaf[1:] == leaf[:-1])
+    if tie.size == 0:
+        return order
+    rows = np.union1d(tie, tie + 1)
+    order = order.copy()
+    order[rows] = order[rows[np.lexsort((ids[order[rows]], leaf[rows]))]]
+    return order
 
 
 def tombstone_bits(n: int) -> int:
@@ -176,7 +223,13 @@ class Segment:
                 symbol instead of 8 — an 8/b× host-RAM saving,
                 DESIGN.md §7); merges/compacts unpack on demand through
                 :attr:`sketches`.
-      ids:      (n_seg,) int64 global ids, sorted ascending.
+      ids:      (n_seg,) int64 global ids.  On the bst backend rows are
+                stored in the trie's leaf order (ties by id), so a
+                segment's ``leaf_root[id_leaf]`` lane rises in steps of
+                0 or 1 (the windowed verify, DESIGN.md §6); the other
+                backends keep the order rows were sealed in (a merge
+                concatenates its inputs), so ids follow no order there.
+                ``rows_of`` is the one id -> row lookup.
       live:     (n_seg,) bool tombstone bitmap (False = deleted).
       L, b:     the sketch geometry ``packed`` was packed with.
       serial:   process-monotonic id (auto-assigned); keys every cached
@@ -195,8 +248,19 @@ class Segment:
     L: int
     b: int
     serial: int = dataclasses.field(
-        default_factory=lambda: next(_SEG_SERIALS))
+        default_factory=next_serial)
     payloads: Optional[np.ndarray] = None
+    # id -> row lookup (argsort of ``ids``), built on the first delete
+    _by_id: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def rows_of(self, ids: np.ndarray) -> np.ndarray:
+        """Rows holding the given global ids (ids this segment does not
+        hold are skipped): O(k log n) through a host argsort of ``ids``,
+        built once per segment."""
+        if self._by_id is None:
+            self._by_id = np.argsort(self.ids, kind="stable")
+        return _rows_of(self.ids, ids, self._by_id)
 
     @property
     def sketches(self) -> np.ndarray:
@@ -239,21 +303,23 @@ class _ColumnArena:
     maintained across queries and updated incrementally on lifecycle
     writes instead of re-uploaded per query.
 
-    Attributes (R = total sealed physical rows, T = 1 + Σ per-segment
-    ℓ_s-root counts — slot 0 is the delta buffer's trivial base):
+    Attributes (R = total sealed physical rows, T = Σ per-segment
+    ℓ_s-root counts + 1 — the last slot is the delta buffer's trivial
+    base, so delta columns appended after the sealed ones keep the lane
+    rising in steps of 0 or 1):
       cols:      (b, W, R) uint32 — full-length vertical verify columns,
                  segment blocks concatenated in stack order;
       base_idx:  (R,) int32 device — per-column index into the
                  concatenated root base plane (the segment-offset lane):
-                 ``1 + root_offset[s] + leaf_root[id_leaf[row]]``;
+                 ``root_offset[s] + leaf_root[id_leaf[row]]``, rising
+                 in steps of 0 or 1 (rows in trie order);
       gids:      (R,) int32 device — global id per column (selection
                  labels);
       live:      (R,) bool device — liveness lanes; ``delete`` flips
                  lanes in place (one scatter), never rebuilding;
       col_ids:   (R,) int64 host — global id per column (result labels);
       col_off:   dict serial -> first column of that segment's block;
-      root_off:  dict serial -> first root slot of that segment;
-      t_root_total: Σ per-segment root counts (plane width minus 1);
+      t_root_total: Σ per-segment root counts (the delta's slot);
       serials:   the segment-stack fingerprint this arena matches.
     """
 
@@ -265,8 +331,19 @@ class _ColumnArena:
         self.live: Optional[jnp.ndarray] = None
         self.col_ids = np.zeros((0,), np.int64)
         self.col_off: Dict[int, int] = {}
-        self.root_off: Dict[int, int] = {}
         self.t_root_total = 0
+        self._idx_host = np.zeros((0,), np.int32)
+        self._windows: Dict[int, WindowPlan] = {}
+
+    def windows(self, ndb: int) -> WindowPlan:
+        """Window plan of the verify lane — sealed columns, then ``ndb``
+        delta columns at the delta's slot — memoized per delta bucket."""
+        plan = self._windows.get(ndb)
+        if plan is None:
+            plan = self._windows[ndb] = ops.plan_windows(np.concatenate(
+                [self._idx_host,
+                 np.full(ndb, self.t_root_total, np.int32)]))
+        return plan
 
     @property
     def n_cols(self) -> int:
@@ -455,7 +532,7 @@ def fused_scope_tables() -> Dict[str, Dict[str, str]]:
 def _take_columns(plane: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """plane[:, idx], one 1-D gather per row (``lax.map``): a single
     column gather lays its slices out row-minor and pads the short row
-    axis to 128 lanes on TPU (see ``hamming_kernel._gather_base``)."""
+    axis to 128 lanes on TPU."""
     return jax.lax.map(lambda row: row[idx], plane)
 
 
@@ -755,7 +832,7 @@ class SegmentedIndex:
         # both expose the same maintenance surface (serials / live /
         # col_off / col_ids / array_bytes)
         self._arena: Optional[object] = None
-        self._fused_id = next(_SEG_SERIALS)             # per-index cache scope
+        self._fused_id = next_serial()                  # per-index cache scope
         self._fused_stamp: Tuple = ()                   # (serials, gen)
         self.counters = {"flushes": 0, "merges": 0, "compactions": 0,
                          "inserted": 0, "deleted": 0}
@@ -838,29 +915,24 @@ class SegmentedIndex:
     def delete(self, ids) -> int:
         """Tombstone global ids (scalar or (k,) array-like); returns the
         number of ids newly deleted (already-dead or unknown ids are
-        ignored).  O(k log n) searchsorted per container — no index is
-        rebuilt and compiled searchers stay valid (liveness is traced).
-        The arena's device liveness lanes are flipped in place with one
-        scatter (DESIGN.md §6) — deletes never re-upload columns."""
+        ignored).  O(k log n) per container — a binary search of the
+        delta's ascending ids, each segment's id -> row lookup — no
+        index is rebuilt and compiled searchers stay valid (liveness is
+        traced).  The arena's device liveness lanes are flipped in place
+        with one scatter (DESIGN.md §6) — deletes never re-upload
+        columns."""
         ids = np.unique(np.atleast_1d(np.asarray(ids, dtype=np.int64)))
         if self.store is not None and ids.size:
             self.store.log_delete(ids)           # write-ahead: log, then apply
         newly = 0
         arena = self._arena
         lanes: List[np.ndarray] = []     # arena columns going dead
-        containers: List[Tuple[np.ndarray, np.ndarray, Optional[int]]] = [
-            (self._delta_ids, self._delta_live, None)]
-        containers += [
-            (seg.ids, seg.live,
+        hits = [(_rows_of(self._delta_ids, ids), self._delta_live, None)]
+        hits += [
+            (seg.rows_of(ids), seg.live,
              arena.col_off.get(seg.serial) if arena is not None else None)
             for seg in self.segments]
-        for id_arr, live_arr, col0 in containers:
-            if id_arr.size == 0:
-                continue
-            pos = np.searchsorted(id_arr, ids)
-            ok = (pos < id_arr.size) & (
-                id_arr[np.minimum(pos, id_arr.size - 1)] == ids)
-            sel = pos[ok]
+        for sel, live_arr, col0 in hits:
             newly += int(live_arr[sel].sum())
             live_arr[sel] = False
             if col0 is not None and sel.size:
@@ -879,14 +951,10 @@ class SegmentedIndex:
         seg = None
         if live.any():
             with _obs_span("seal", cat="ingest", rows=int(live.sum())):
-                sk = self._delta_sk[live]
-                ids = self._delta_ids[live]
                 pay = (self._delta_pay[live]
                        if self._delta_pay is not None else None)
-                seg = Segment(index=self._traced_build(sk),
-                              packed=self._traced_pack(sk), ids=ids,
-                              live=np.ones(len(ids), bool), L=self.L,
-                              b=self.b, payloads=pay)
+                seg = self._seal(self._delta_sk[live], self._delta_ids[live],
+                                 pay)
             self.segments.append(seg)
             self.counters["flushes"] += 1
             self._emit("flush", rows=seg.n)
@@ -924,19 +992,10 @@ class SegmentedIndex:
             if self.payload_words is not None:
                 pay = np.concatenate([a.payloads[a.live],
                                       b_.payloads[b_.live]])
-            # keep ids sorted for delete
-            order = np.argsort(ids, kind="stable")
-            sk, ids = sk[order], ids[order]
-            if pay is not None:
-                pay = pay[order]
             lo, hi = min(i, j), max(i, j)
             del self.segments[hi], self.segments[lo]
             if len(ids):
-                self.segments.insert(lo, Segment(
-                    index=self._traced_build(sk),
-                    packed=self._traced_pack(sk), ids=ids,
-                    live=np.ones(len(ids), bool), L=self.L, b=self.b,
-                    payloads=pay))
+                self.segments.insert(lo, self._seal(sk, ids, pay))
         self.counters["merges"] += 1
         self._emit("merge", rows=int(len(ids)))
         if self.store is not None:
@@ -981,13 +1040,10 @@ class SegmentedIndex:
             if seg.n_live == 0:
                 out[si] = None
             else:
-                sk, ids = seg.sketches[seg.live], seg.ids[seg.live]
                 pay = (seg.payloads[seg.live]
                        if seg.payloads is not None else None)
-                out[si] = Segment(index=self._build(sk),
-                                  packed=pack_vertical(sk, self.b), ids=ids,
-                                  live=np.ones(len(ids), bool), L=self.L,
-                                  b=self.b, payloads=pay)
+                out[si] = self._seal(seg.sketches[seg.live],
+                                     seg.ids[seg.live], pay)
             done += 1
         self.segments = [s for s in out if s is not None]
         self.counters["compactions"] += done
@@ -1303,18 +1359,55 @@ class SegmentedIndex:
         if ids.size:
             self.n_ids = max(self.n_ids, int(ids.max()) + 1)
 
-    def _build(self, sk: np.ndarray):
+    def _build(self, sk: np.ndarray, ids: np.ndarray):
+        """Build one segment's index over rows ``sk`` -> (index, order):
+        ``order`` is the row permutation that stores the rows in the
+        trie's leaf order, equal rows by ``ids`` (bst backend; None when
+        the rows are in that order already, or on the other backends).
+        The order is the trie build's own sort; only runs of equal rows
+        are sorted again."""
         if self.backend == "multi":
-            return build_multi_index(sk, self.b, self.mi_blocks, self.lam)
+            return build_multi_index(sk, self.b, self.mi_blocks,
+                                     self.lam), None
         if self.backend == "sharded":
             return build_sharded_bst(sk, self.b,
                                      max(1, min(self.n_shards, len(sk))),
-                                     self.lam)
-        return build_bst(sk, self.b, self.lam)
+                                     self.lam), None
+        trie = build_trie_levels(sk, self.b)
+        order = trie.ids_sorted
+        leaf = trie.id_leaf[order]                 # non-decreasing
+        order = _ties_by_id(order, leaf, ids)
+        if np.array_equal(order, np.arange(len(order))):
+            return build_bst(sk, self.b, self.lam, trie=trie), None
+        # the index addresses rows through id_leaf only: permuting the
+        # rows permutes it into the (non-decreasing) leaf of each row,
+        # set before the build so that only that lane goes to the device
+        # (``build_bst`` reads the rows themselves only without a trie)
+        trie = dataclasses.replace(trie, id_leaf=leaf,
+                                   ids_sorted=np.arange(len(leaf)))
+        return build_bst(sk, self.b, self.lam, trie=trie), order
 
-    def _traced_build(self, sk: np.ndarray):
+    def _seal(self, sk: np.ndarray, ids: np.ndarray,
+              pay: Optional[np.ndarray], *, live: Optional[np.ndarray] = None,
+              packed: Optional[np.ndarray] = None,
+              serial: Optional[int] = None) -> Segment:
+        """One sealed segment over rows (sketches, ids, payloads), its
+        rows stored in the trie's leaf order (``_build``).  ``live`` /
+        ``packed`` / ``serial`` restore a checkpointed segment (all
+        live, packed here and a fresh serial otherwise)."""
         with _obs_span("trie_build", cat="ingest", rows=len(sk)):
-            return self._build(sk)
+            index, order = self._build(sk, ids)
+        if order is not None:
+            sk, ids = sk[order], ids[order]
+            pay = pay[order] if pay is not None else None
+            live = live[order] if live is not None else None
+            packed = packed[order] if packed is not None else None
+        return Segment(
+            index=index,
+            packed=self._traced_pack(sk) if packed is None else packed,
+            ids=ids, live=np.ones(len(ids), bool) if live is None else live,
+            L=self.L, b=self.b, payloads=pay,
+            serial=next_serial() if serial is None else int(serial))
 
     def _traced_pack(self, sk: np.ndarray) -> np.ndarray:
         with _obs_span("pack_vertical", cat="ingest", rows=len(sk)):
@@ -1591,7 +1684,7 @@ class SegmentedIndex:
         W = max(1, (self.L + 31) // 32)
         cols_np, idx_np, gid_np, live_np, cid_np = [], [], [], [], []
         col0 = int(ar.col_ids.shape[0])
-        root0 = 1 + ar.t_root_total          # slot 0: delta's trivial base
+        root0 = ar.t_root_total
         for seg in new_segs:
             cols_np.append(np.transpose(seg.packed, (1, 2, 0)))
             leaf_root = np.asarray(seg.index.tail.leaf_root)
@@ -1601,7 +1694,6 @@ class SegmentedIndex:
             live_np.append(seg.live.copy())
             cid_np.append(seg.ids)
             ar.col_off[seg.serial] = col0
-            ar.root_off[seg.serial] = root0
             col0 += seg.n
             root0 += int(seg.index.tail.t_root)
         empty_cols = jnp.zeros((self.b, W, 0), jnp.uint32)
@@ -1620,9 +1712,11 @@ class SegmentedIndex:
             ar.live = jnp.concatenate(
                 [old[3], jnp.asarray(np.concatenate(live_np))])
             ar.col_ids = np.concatenate([ar.col_ids] + cid_np)
+            ar._idx_host = np.concatenate([ar._idx_host] + idx_np)
+            ar._windows = {}
         else:
             ar.cols, ar.base_idx, ar.gids, ar.live = old
-        ar.t_root_total = root0 - 1
+        ar.t_root_total = root0
         ar.serials = serials
         self._arena = ar
         return ar
@@ -1706,6 +1800,7 @@ class SegmentedIndex:
                      for ix in indexes]
         t_roots = [int(ix.tail.t_root) for ix in indexes]
         cols0, idx0, gids0 = arena.cols, arena.base_idx, arena.gids
+        t_slot = arena.t_root_total
         b_, block_m = self.b, self.block_m
 
         @jax.jit
@@ -1713,7 +1808,8 @@ class SegmentedIndex:
             _note_trace()
             qsi = qs.astype(jnp.int32)
             m = qsi.shape[0]
-            planes = [jnp.zeros((m, 1), jnp.int32)]  # slot 0: delta base
+            ndb = delta_vert.shape[-1]
+            planes = []
             overflow = jnp.zeros((m,), jnp.int32)
             with jax.named_scope("rung.traverse"):
                 for ix, caps, t_root in zip(indexes, caps_list, t_roots):
@@ -1725,17 +1821,20 @@ class SegmentedIndex:
                     planes.append(scatter_root_plane(
                         ids, jnp.zeros_like(dists), valid, m, t_root))
                     overflow = overflow + ov
+                # last slot: the delta columns' trivial base
+                planes.append(jnp.zeros((m, 1), jnp.int32))
                 base_plane = jnp.concatenate(planes, axis=1)
             with jax.named_scope("rung.verify"):
                 cols = jnp.concatenate([cols0, delta_vert], axis=-1)
                 live = jnp.concatenate([live_sealed, delta_live])
                 base_idx = jnp.concatenate(
-                    [idx0, jnp.zeros((delta_vert.shape[-1],), jnp.int32)])
+                    [idx0, jnp.full((ndb,), t_slot, jnp.int32)])
+                win = arena.windows(ndb)
                 q_vert = jnp.transpose(pack_vertical_jax(qsi, b_),
                                        (1, 2, 0))
                 hm, dist = ops.sparse_verify_arena(
                     cols, q_vert, base_plane, base_idx, live, tau=tau,
-                    block_m=block_m)
+                    windows=(win.start, win.steps), block_m=block_m)
                 dist = jnp.where(hm > 0, dist, BIG)
             if kind == "cols":
                 return dist, overflow.sum()
@@ -1770,6 +1869,7 @@ class SegmentedIndex:
         store = self._refresh_store()
         plan = store.plan()
         order, inv = store.order()
+        root_order = store.root_order()
         cap = CAP_MAX_DEFAULT << rung
         indexes = [seg.index for seg in self.segments]
         caps_list = [frontier_capacities(ix.t, self.b, tau, cap)
@@ -1781,6 +1881,7 @@ class SegmentedIndex:
         corpus = {"indexes": indexes,
                   "cols": [g.cols_hot for g in plan],
                   "base_idx": [g.base_idx for g in plan],
+                  "windows": [g.windows for g in plan],
                   "gids": store.gids, "order": order, "inv": inv}
 
         def run(corpus, qs, live_sealed, staged, delta_vert, delta_live,
@@ -1788,7 +1889,7 @@ class SegmentedIndex:
             _note_trace()
             qsi = qs.astype(jnp.int32)
             m = qsi.shape[0]
-            planes = [jnp.zeros((m, 1), jnp.int32)]  # slot 0: delta base
+            planes = []
             overflow = jnp.zeros((m,), jnp.int32)
             with jax.named_scope("rung.traverse"):
                 for ix, caps, t_root in zip(corpus["indexes"], caps_list,
@@ -1798,12 +1899,18 @@ class SegmentedIndex:
                     planes.append(scatter_root_plane(
                         ids, dists, valid, m, t_root))
                     overflow = overflow + ov
-                base_plane = jnp.concatenate(planes, axis=1)
+                # roots in the plan's column order: each group's lane
+                # rises in steps of 0 or 1 through this plane
+                base_plane = (jnp.concatenate([planes[i]
+                                               for i in root_order], axis=1)
+                              if planes else jnp.zeros((m, 0), jnp.int32))
             order, inv = corpus["order"], corpus["inv"]
             dist_parts: List[jnp.ndarray] = []
             with jax.named_scope("rung.verify"):
-                for gi, (geom, cols_hot, base_idx, slab) in enumerate(zip(
-                        geoms, corpus["cols"], corpus["base_idx"], staged)):
+                for gi, (geom, cols_hot, base_idx, windows, slab) in \
+                        enumerate(zip(geoms, corpus["cols"],
+                                      corpus["base_idx"],
+                                      corpus["windows"], staged)):
                     axis = 0 if geom.packed else -1
                     parts = [p for p in (cols_hot, slab) if p is not None]
                     cols_g = (parts[0] if len(parts) == 1
@@ -1816,21 +1923,21 @@ class SegmentedIndex:
                         qw = pack_suffix_words_jax(qsi[:, L - S:], b_)
                         hm, d = ops.sparse_verify_arena_packed(
                             cols_g, qw, base_plane, base_idx, live_g, b=b_,
-                            S=S, tau=tau, block_m=block_m)
+                            S=S, tau=tau, windows=windows, block_m=block_m)
                     else:
                         qv = jnp.transpose(
                             pack_vertical_jax(qsi[:, L - S:], b_),
                             (1, 2, 0))
                         hm, d = ops.sparse_verify_arena(
                             cols_g, qv, base_plane, base_idx, live_g,
-                            tau=tau, block_m=block_m)
+                            tau=tau, windows=windows, block_m=block_m)
                     dist_parts.append(jnp.where(hm > 0, d, BIG))
                 dist_sealed = (jnp.concatenate(dist_parts, axis=1)
                                if dist_parts
                                else jnp.zeros((m, 0), jnp.int32))
             # the delta buffer scans full-length (its rows have no trie,
             # hence no ℓ_s to slice at) — same arithmetic as the full
-            # arena's trivial base slot 0
+            # arena's trivial delta slot
             with jax.named_scope("rung.delta_scan"):
                 q_vert = jnp.transpose(pack_vertical_jax(qsi, b_),
                                        (1, 2, 0))
@@ -2005,6 +2112,7 @@ class SegmentedIndex:
                 with _obs_span("rung_program", cat="device"):
                     fn = self._fused_fn(kind, tau, rung, kk)
                 _dispatch("fused")
+                self._count_windows(mb, delta_vert.shape[-1])
                 with _obs_span("rung_launch", cat="device"):
                     args = (jnp.asarray(qs_p), seg_arg)
                     if staged is not None:
@@ -2020,6 +2128,25 @@ class SegmentedIndex:
             if done:
                 return out
             rung += 1
+
+    def _count_windows(self, mb: int, ndb: int) -> None:
+        """Tally one fused launch's windowed verify (bst backend): every
+        arena kernel call wide enough for the kernel path (``ops`` runs
+        narrower ones on the oracle) expands its column blocks' windows
+        once per query tile of the ``mb``-row bucket."""
+        if self.backend != "bst":
+            return
+        if self.layout == "suffix":
+            plans = [(len(g.perm), len(g.windows[0]), g.window_roots)
+                     for g in self._refresh_store().plan()]
+        else:
+            ar = self._refresh_arena()
+            win = ar.windows(ndb)
+            plans = [(ar.n_cols + ndb, len(win.start), win.roots)]
+        tiles = -(-mb // min(self.block_m, mb))
+        for n, blocks, roots in plans:
+            if ops.arena_uses_kernel(n):
+                _tally_windows(n, blocks * tiles, roots * tiles)
 
     def _fused_columns(self, qs: np.ndarray,
                        tau: int) -> Tuple[np.ndarray, np.ndarray, int]:

@@ -40,18 +40,34 @@ Every verify kernel emits one (m, n) int32 plane — the exact total
 distance, clamped to BIG on pruned or dead lanes; the survival mask is
 ``dist <= tau`` (τ < BIG), derived outside the kernel so XLA fuses it
 into its consumer instead of writing a second plane to HBM.
+
+Arena verify reads each column's prefix distance from an (m, T)
+per-root base plane through the column's ``base_idx`` lane.  Sealed
+rows are stored in trie leaf order, so that lane rises in steps of 0 or
+1 and any w consecutive columns need at most w consecutive roots: each
+column block DMAs a short window of the base plane (its start known
+before the block runs, by scalar prefetch) and expands it inside VMEM
+with 128-lane gathers (DESIGN.md §6).  ``plan_windows`` computes the
+window starts on the host and refuses a lane that breaks the invariant.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_N = 2048
 DEFAULT_BLOCK_M = 8
+
+# Lane width of one vreg: the windowed base expansion works in 128-lane
+# sub-blocks, and a window starts on a 128-lane tile.
+LANES = 128
 
 # Distance sentinel for pruned lanes.  Matches core.bst.BIG (kernels must
 # not import core); verified equal in tests/test_kernels.py.
@@ -156,17 +172,6 @@ def _packed_tile_distances(db, q, *, b: int, S: int):
     return jax.lax.population_count(acc).astype(jnp.int32)
 
 
-def _verify_packed_kernel(db_ref, q_ref, base_ref, dist_ref, *, b: int,
-                          S: int):
-    """Packed-suffix twin of ``_verify_kernel``: the per-column payload
-    is one uint32 word (the b bit planes of the S-symbol suffix below
-    the segment's ℓ_s collapse depth) instead of (b, W) full-length
-    words — the prefix part of the distance arrives through the base
-    plane (DESIGN.md §7)."""
-    dist = _packed_tile_distances(db_ref[...], q_ref[...], b=b, S=S)
-    dist_ref[...] = jnp.minimum(dist + base_ref[...], BIG)
-
-
 def _verify_call(kernel, db, q_rows, base, *, tau: int, block_m: int,
                  block_n: int, interpret: bool):
     """Launch one verify kernel on the (m/block_m, n/block_n) grid and
@@ -224,18 +229,175 @@ def sparse_verify_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     return mask[0], dist[0]
 
 
-def _gather_base(base_plane: jnp.ndarray, base_idx: jnp.ndarray,
-                 live: jnp.ndarray) -> jnp.ndarray:
-    """(m, T) per-root base plane -> the dense (m, n) per-column base
-    plane the verify kernels stream: an XLA gather through the
-    segment-offset lane, BIG on dead lanes (DESIGN.md §6).  One 1-D
-    gather per query row (``lax.map``): a single (m, T)[:, idx] gather
-    lays its slices out as (n, m) and pads m to 128 lanes on TPU — a
-    temporary of 512 bytes per column."""
-    live = live != 0
-    return jax.lax.map(
-        lambda row: jnp.where(live, row[base_idx], BIG),
-        base_plane.astype(jnp.int32))
+class WindowPlan(NamedTuple):
+    """Host plan of the windowed base expansion over one ``base_idx``
+    lane, padded (with its last value) to a ``block_n`` multiple.
+
+    start: (n_blocks,) int32 — the 128-lane tile of the base plane that
+           holds the block's first root (the window start);
+    steps: (n_blocks,) int32 — bit s-1 set when 128-lane sub-block s
+           starts one tile later than sub-block s-1;
+    roots: Σ over blocks of the roots the block's lanes span."""
+
+    start: np.ndarray
+    steps: np.ndarray
+    roots: int
+
+
+def plan_windows(base_idx, block_n: int = DEFAULT_BLOCK_N) -> WindowPlan:
+    """Window starts of every ``block_n`` column block of an arena lane.
+
+    The lane must rise in steps of 0 or 1 (sealed rows in trie order,
+    segments' roots laid out in column order); then a block's lanes
+    span at most ``block_n`` roots from its first, and each 128-lane
+    sub-block's at most two consecutive 128-lane tiles of the base
+    plane.  Raises ValueError on any other lane: there is no gather to
+    fall back to."""
+    if block_n % LANES or not 0 < block_n // LANES <= 32:
+        raise ValueError(f"block_n={block_n} must be 128·k with k <= 32")
+    idx = np.asarray(base_idx, np.int64)
+    n_sub = block_n // LANES
+    if idx.size == 0:
+        return WindowPlan(np.zeros(0, np.int32), np.zeros(0, np.int32), 0)
+    step = np.diff(idx)
+    if idx[0] < 0 or (step.size and (step.min() < 0 or step.max() > 1)):
+        raise ValueError(
+            "arena base_idx must rise in steps of 0 or 1 (sealed rows in "
+            "trie leaf order, roots in column order) for the windowed "
+            "verify")
+    pad = (-idx.size) % block_n
+    if pad:
+        idx = np.concatenate([idx, np.full(pad, idx[-1])])
+    tiles = (idx[::LANES] // LANES).reshape(-1, n_sub)
+    steps = (np.diff(tiles, axis=1) << np.arange(n_sub - 1)).sum(axis=1)
+    blocks = idx.reshape(-1, block_n)
+    roots = int((blocks[:, -1] - blocks[:, 0] + 1).sum())
+    return WindowPlan(tiles[:, 0].astype(np.int32), steps.astype(np.int32),
+                      roots)
+
+
+def _lane_gather(x, idx):
+    """(rows, 128) x (rows, 128) in-tile lane indices -> x[r, idx[r, c]]:
+    the one gather Mosaic lowers (``tpu.dynamic_gather`` within a vreg
+    row), spelled with explicit batching dims so a one-row tile lowers
+    too."""
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    return jax.lax.gather(x, idx[..., None], dn, (1, 1),
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _window_bases(start_ref, steps_ref, lo_ref, hi_ref, idx_ref, live_ref):
+    """Expand this block's base-plane window into per-column bases: one
+    (BLOCK_M, 128) int32 plane per 128-lane sub-block, BIG on dead
+    lanes.  ``lo_ref`` / ``hi_ref`` are the two ``block_n``-wide chunks
+    of the base plane that hold the window; sub-block s reads tiles t_s
+    and t_s + 1 of them (t_s from the prefetched start and step bits)
+    and gathers each column's root within those 256 lanes."""
+    i = pl.program_id(1)
+    bm = lo_ref.shape[0]
+    n_sub = idx_ref.shape[-1] // LANES
+    start, steps = start_ref[i], steps_ref[i]
+    first = start - start % n_sub          # first tile of the lo chunk
+    t = start % n_sub
+
+    def tile(u):
+        col = pl.multiple_of((u % n_sub) * LANES, LANES)
+        return jnp.where(u < n_sub, lo_ref[:, pl.ds(col, LANES)],
+                         hi_ref[:, pl.ds(col, LANES)])
+
+    bases = []
+    for s in range(n_sub):
+        if s:
+            t = t + ((steps >> (s - 1)) & 1)
+        lanes = slice(s * LANES, (s + 1) * LANES)
+        off = jnp.broadcast_to(idx_ref[:, lanes] - (first + t) * LANES,
+                               (bm, LANES))      # in [0, 256)
+        pos = off & (LANES - 1)
+        base = jnp.where(off < LANES, _lane_gather(tile(t), pos),
+                         _lane_gather(tile(t + 1), pos))
+        bases.append(jnp.where(live_ref[:, lanes] != 0, base, BIG))
+    return bases
+
+
+def _store_totals(dist_ref, dist, bases):
+    """Write prefix + suffix totals, clamped to BIG, sub-block by
+    sub-block."""
+    for s, base in enumerate(bases):
+        lanes = slice(s * LANES, (s + 1) * LANES)
+        dist_ref[:, lanes] = jnp.minimum(dist[:, lanes] + base, BIG)
+
+
+def _verify_window_kernel(start_ref, steps_ref, lo_ref, hi_ref, idx_ref,
+                          live_ref, db_ref, q_ref, dist_ref, *, b: int,
+                          W: int):
+    """Arena verify over (b, W, BLOCK_N) vertical columns: suffix (or
+    full-length) distance plus the windowed per-column base."""
+    _store_totals(dist_ref, _tile_distances(db_ref, q_ref, b=b, W=W),
+                  _window_bases(start_ref, steps_ref, lo_ref, hi_ref,
+                                idx_ref, live_ref))
+
+
+def _verify_packed_kernel(start_ref, steps_ref, lo_ref, hi_ref, idx_ref,
+                          live_ref, db_ref, q_ref, dist_ref, *, b: int,
+                          S: int):
+    """Packed-suffix twin of ``_verify_window_kernel``: the per-column
+    payload is one uint32 word (the b bit planes of the S-symbol suffix
+    below the segment's ℓ_s collapse depth) instead of (b, W)
+    full-length words — the prefix part of the distance arrives through
+    the base plane (DESIGN.md §7)."""
+    _store_totals(dist_ref,
+                  _packed_tile_distances(db_ref[...], q_ref[...], b=b, S=S),
+                  _window_bases(start_ref, steps_ref, lo_ref, hi_ref,
+                                idx_ref, live_ref))
+
+
+def _window_call(kernel, db, q_rows, base_plane, base_idx, live, windows,
+                 *, tau: int, block_m: int, block_n: int, interpret: bool):
+    """Launch one windowed arena verify on the (m/block_m, n/block_n)
+    grid.  ``windows`` = (start, steps) of ``plan_windows`` over the
+    padded lane; the base plane is padded with BIG to whole ``block_n``
+    chunks plus one, so every window's second chunk exists.  Returns
+    ((m, n) int32 survival masks, (m, n) int32 BIG-clamped totals)."""
+    m, T = base_plane.shape
+    n = db.shape[-1]
+    start, steps = windows
+    n_sub = block_n // LANES
+    assert n % block_n == 0, (n, block_n)
+    assert m % block_m == 0, (m, block_m)
+    assert q_rows.shape[0] == m, (q_rows.shape, m)
+    assert base_idx.shape == (n,) and live.shape == (n,), (n,)
+    assert start.shape == steps.shape == (n // block_n,), (start.shape, n)
+    t_pad = (pl.cdiv(T, block_n) + 1) * block_n
+    plane = jnp.pad(base_plane.astype(jnp.int32), ((0, 0), (0, t_pad - T)),
+                    constant_values=BIG)
+    lead = (0,) * (db.ndim - 1)
+    lane = lambda j, i, st, sp: (0, i)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(m // block_m, n // block_n),
+        in_specs=[
+            pl.BlockSpec((block_m, block_n),
+                         lambda j, i, st, sp: (j, st[i] // n_sub)),
+            pl.BlockSpec((block_m, block_n),
+                         lambda j, i, st, sp: (j, st[i] // n_sub + 1)),
+            pl.BlockSpec((1, block_n), lane),
+            pl.BlockSpec((1, block_n), lane),
+            pl.BlockSpec(tuple(db.shape[:-1]) + (block_n,),
+                         lambda j, i, st, sp: lead + (i,)),
+            pl.BlockSpec((block_m, q_rows.shape[1]),
+                         lambda j, i, st, sp: (j, 0))],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda j, i, st, sp: (j, i)))
+    dist = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        interpret=interpret,
+    )(jnp.asarray(start, jnp.int32), jnp.asarray(steps, jnp.int32),
+      plane, plane, base_idx.astype(jnp.int32).reshape(1, n),
+      live.astype(jnp.int32).reshape(1, n), db, q_rows)
+    return (dist <= tau).astype(jnp.int32), dist
 
 
 @functools.partial(jax.jit,
@@ -245,8 +407,8 @@ def sparse_verify_arena_packed_pallas(db_words: jnp.ndarray,
                                       q_words: jnp.ndarray,
                                       base_plane: jnp.ndarray,
                                       base_idx: jnp.ndarray,
-                                      live: jnp.ndarray, *, b: int, S: int,
-                                      tau: int,
+                                      live: jnp.ndarray, windows, *,
+                                      b: int, S: int, tau: int,
                                       block_m: int = DEFAULT_BLOCK_M,
                                       block_n: int = DEFAULT_BLOCK_N,
                                       interpret: bool = False):
@@ -256,23 +418,22 @@ def sparse_verify_arena_packed_pallas(db_words: jnp.ndarray,
     db_words:   (n,) uint32 — one packed suffix word per column;
     q_words:    (m,) uint32 — the query suffixes in the same packing;
     base_plane: (m, T) int32 — concatenated per-(segment, root) *prefix*
-                distances (BIG = pruned), slot 0 the delta's trivial 0;
-    base_idx:   (n,) int32 segment-offset lane; live: (n,) int32.
+                distances (BIG = pruned);
+    base_idx:   (n,) int32 segment-offset lane, rising in steps of 0 or
+                1; live: (n,) int32;
+    windows:    (start, steps) of ``plan_windows(base_idx, block_n)``.
 
     Same (m/block_m, n/block_n) query-tiled grid and return contract as
     ``sparse_verify_arena_pallas`` — only the column payload shrinks,
     from b·W words to one."""
     n = db_words.shape[-1]
     m = q_words.shape[-1]
-    assert base_plane.shape[0] == m, (base_plane.shape, m)
-    assert base_idx.shape == (n,), (base_idx.shape, n)
-    assert live.shape == (n,), (live.shape, n)
     kernel = functools.partial(_verify_packed_kernel, b=b, S=S)
-    return _verify_call(
+    return _window_call(
         kernel, db_words.astype(jnp.uint32).reshape(1, n),
-        q_words.astype(jnp.uint32).reshape(m, 1),
-        _gather_base(base_plane, base_idx, live), tau=tau, block_m=block_m,
-        block_n=block_n, interpret=interpret)
+        q_words.astype(jnp.uint32).reshape(m, 1), base_plane, base_idx,
+        live, windows, tau=tau, block_m=block_m, block_n=block_n,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -280,38 +441,34 @@ def sparse_verify_arena_packed_pallas(db_words: jnp.ndarray,
 def sparse_verify_arena_pallas(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
                                base_plane: jnp.ndarray,
                                base_idx: jnp.ndarray, live: jnp.ndarray,
-                               *, tau: int,
+                               windows, *, tau: int,
                                block_m: int = DEFAULT_BLOCK_M,
                                block_n: int = DEFAULT_BLOCK_N,
                                interpret: bool = False):
     """Fused multi-segment verify over a **column arena** (DESIGN.md §6).
 
     paths_vert: (b, W, n) uint32 — concatenated verify columns of every
-                segment plus the delta buffer (one column per physical
-                row, full-length vertical packing);
+                segment (plus, in the full-length layout, the delta
+                buffer's);
     q_vert:     (b, W, m) uint32 query planes;
     base_plane: (m, T) int32 — the concatenated per-(segment, ℓ_s-root)
                 base-distance plane (slot semantics are the caller's:
-                the segmented index stores 0 = reached / BIG = pruned,
-                with slot 0 the delta buffer's trivial base);
+                the full-length layout stores 0 = reached / BIG =
+                pruned, with its last slot the delta buffer's trivial
+                base);
     base_idx:   (n,) int32 — per-column index into ``base_plane``'s T
-                axis (segment columns point at segment_root_offset +
-                their ℓ_s root; delta columns at the trivial slot);
-    live:       (n,) int32 — per-column liveness lane (0 = tombstoned).
+                axis, rising in steps of 0 or 1 (rows in trie order);
+    live:       (n,) int32 — per-column liveness lane (0 = tombstoned);
+    windows:    (start, steps) of ``plan_windows(base_idx, block_n)``.
 
     Returns ((m, n) int32 survival masks, (m, n) int32 totals clamped to
-    BIG).  One launch sweeps every segment and the delta buffer: the
-    per-column base is gathered through the segment-offset lane by XLA
-    into a dense (m, n) plane (Mosaic has no lane gather), which the
-    ``sparse_verify_batch_pallas`` kernel body then streams."""
-    b, W, n = paths_vert.shape
-    m = q_vert.shape[-1]
-    assert base_plane.shape[0] == m, (base_plane.shape, m)
-    assert base_idx.shape == (n,), (base_idx.shape, n)
-    assert live.shape == (n,), (live.shape, n)
-    kernel = functools.partial(_verify_kernel, b=b, W=W)
-    return _verify_call(kernel, paths_vert, _query_rows(q_vert),
-                        _gather_base(base_plane, base_idx, live), tau=tau,
+    BIG).  One launch sweeps every segment: each column block DMAs its
+    window of the base plane and expands it in VMEM — no (m, n) base
+    plane is ever built."""
+    b, W, _ = paths_vert.shape
+    kernel = functools.partial(_verify_window_kernel, b=b, W=W)
+    return _window_call(kernel, paths_vert, _query_rows(q_vert),
+                        base_plane, base_idx, live, windows, tau=tau,
                         block_m=block_m, block_n=block_n,
                         interpret=interpret)
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from . import ref
 from .hamming_kernel import (BIG, DEFAULT_BLOCK_M, DEFAULT_BLOCK_N,
                              exact_rerank_pallas, hamming_distances_pallas,
+                             plan_windows,
                              sparse_verify_arena_packed_pallas,
                              sparse_verify_arena_pallas,
                              sparse_verify_batch_pallas, sparse_verify_pallas)
@@ -181,32 +182,56 @@ def sparse_verify_batch(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     return mask[:m, :n], dist[:m, :n]
 
 
+def _arena_args(base_plane, base_idx, live, windows, *, n_pad: int,
+                m_pad: int):
+    """Pad the arena lanes to ``n_pad`` columns — dead, with the lane's
+    last root, so it still rises in steps of 0 or 1 — and the base plane
+    to ``m_pad`` all-BIG rows; check that a window plan is there."""
+    if windows is None:
+        raise ValueError("the windowed arena verify needs the lane's "
+                         "window plan (ops.plan_windows)")
+    n, m = base_idx.shape[-1], base_plane.shape[0]
+    idx_p = jnp.pad(base_idx.astype(jnp.int32), (0, n_pad - n), mode="edge")
+    live_p = jnp.pad(live.astype(jnp.int32), (0, n_pad - n))  # pads dead
+    base_p = jnp.pad(base_plane.astype(jnp.int32), ((0, m_pad - m), (0, 0)),
+                     constant_values=jnp.int32(BIG))
+    return base_p, idx_p, live_p, (windows[0], windows[1])
+
+
+def arena_uses_kernel(n: int, block_n: int = DEFAULT_BLOCK_N) -> bool:
+    """Whether an arena verify over ``n`` columns runs the windowed
+    kernel; narrower ones run the ``ref`` oracle."""
+    return n >= block_n
+
+
 def sparse_verify_arena(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
                         base_plane: jnp.ndarray, base_idx: jnp.ndarray,
-                        live: jnp.ndarray, *, tau: int,
+                        live: jnp.ndarray, *, tau: int, windows=None,
                         block_m: int = DEFAULT_BLOCK_M,
                         block_n: int = DEFAULT_BLOCK_N,
                         use_kernel: bool | None = None):
     """Fused multi-segment verify over a column arena (DESIGN.md §6).
 
-    paths_vert: (b, W, n) concatenated verify columns (all segments +
-                the delta buffer, one column per physical row);
+    paths_vert: (b, W, n) concatenated verify columns (all segments,
+                plus the delta buffer in the full-length layout; one
+                column per physical row);
     q_vert:     (b, W, m) query planes;
     base_plane: (m, T) per-(segment, root) base distances — T = total
-                ℓ_s roots across segments + 1 trivial slot, ≪ n;
+                ℓ_s roots across segments (+ the delta's slot), ≪ n;
     base_idx:   (n,) int32 per-column index into the T axis (the
-                segment-offset lane);
+                segment-offset lane), rising in steps of 0 or 1;
     live:       (n,) bool per-column liveness;
+    windows:    ``plan_windows(base_idx, block_n)`` (its start and
+                steps), needed on the kernel path;
     returns ((m, n) int32 masks, (m, n) int32 totals, BIG-clamped).
 
-    One launch sweeps every segment and the delta buffer: pads n to a
-    ``block_n`` multiple with dead lanes (live=False -> BIG, can never
-    survive) and m to a ``block_m`` multiple with all-zero queries (rows
-    sliced off)."""
+    One launch sweeps every segment: pads n to a ``block_n`` multiple
+    with dead lanes (live=False -> BIG, can never survive) and m to a
+    ``block_m`` multiple with all-zero queries (rows sliced off)."""
     n = paths_vert.shape[-1]
     m = q_vert.shape[-1]
     if use_kernel is None:
-        use_kernel = n >= block_n
+        use_kernel = arena_uses_kernel(n, block_n)
     _count("sparse_verify_arena", use_kernel)
     if not use_kernel:
         mask, dist = ref.sparse_verify_arena_ref(paths_vert, q_vert,
@@ -216,14 +241,11 @@ def sparse_verify_arena(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
     block_m = min(block_m, m)  # never compute more pad-query rows than m
     paths_p = _pad_lanes(paths_vert, block_n)
     q_p = _pad_lanes(q_vert, block_m)
-    pad_n = paths_p.shape[-1] - n
-    pad_m = q_p.shape[-1] - m
-    base_p = jnp.pad(base_plane.astype(jnp.int32), ((0, pad_m), (0, 0)),
-                     constant_values=jnp.int32(BIG))
-    idx_p = jnp.pad(base_idx.astype(jnp.int32), (0, pad_n))
-    live_p = jnp.pad(live.astype(jnp.int32), (0, pad_n))  # pads dead
+    base_p, idx_p, live_p, win = _arena_args(
+        base_plane, base_idx, live, windows, n_pad=paths_p.shape[-1],
+        m_pad=q_p.shape[-1])
     mask, dist = sparse_verify_arena_pallas(
-        paths_p, q_p, base_p, idx_p, live_p, tau=tau, block_m=block_m,
+        paths_p, q_p, base_p, idx_p, live_p, win, tau=tau, block_m=block_m,
         block_n=block_n, interpret=_interpret())
     return mask[:m, :n], dist[:m, :n]
 
@@ -231,7 +253,7 @@ def sparse_verify_arena(paths_vert: jnp.ndarray, q_vert: jnp.ndarray,
 def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
                                base_plane: jnp.ndarray,
                                base_idx: jnp.ndarray, live: jnp.ndarray,
-                               *, b: int, S: int, tau: int,
+                               *, b: int, S: int, tau: int, windows=None,
                                block_m: int = DEFAULT_BLOCK_M,
                                block_n: int = DEFAULT_BLOCK_N,
                                use_kernel: bool | None = None):
@@ -245,7 +267,8 @@ def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
                 pruned; the traversal's exact distance, not 0/BIG —
                 total = prefix + suffix is the full-length Hamming
                 distance bit for bit);
-    base_idx:   (n,) int32 segment-offset lane;  live: (n,) bool;
+    base_idx:   (n,) int32 segment-offset lane, rising in steps of 0 or
+                1;  live: (n,) bool;  windows: as ``sparse_verify_arena``;
     returns ((m, n) int32 masks, (m, n) int32 totals, BIG-clamped).
 
     Same padding discipline as ``sparse_verify_arena``: n pads with dead
@@ -253,7 +276,7 @@ def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
     n = db_words.shape[-1]
     m = q_words.shape[-1]
     if use_kernel is None:
-        use_kernel = n >= block_n
+        use_kernel = arena_uses_kernel(n, block_n)
     _count("sparse_verify_arena_packed", use_kernel)
     if not use_kernel:
         mask, dist = ref.sparse_verify_arena_packed_ref(
@@ -262,14 +285,11 @@ def sparse_verify_arena_packed(db_words: jnp.ndarray, q_words: jnp.ndarray,
     block_m = min(block_m, m)  # never compute more pad-query rows than m
     db_p = _pad_lanes(db_words.astype(jnp.uint32), block_n)
     q_p = _pad_lanes(q_words.astype(jnp.uint32), block_m)
-    pad_n = db_p.shape[-1] - n
-    pad_m = q_p.shape[-1] - m
-    base_p = jnp.pad(base_plane.astype(jnp.int32), ((0, pad_m), (0, 0)),
-                     constant_values=jnp.int32(BIG))
-    idx_p = jnp.pad(base_idx.astype(jnp.int32), (0, pad_n))
-    live_p = jnp.pad(live.astype(jnp.int32), (0, pad_n))  # pads dead
+    base_p, idx_p, live_p, win = _arena_args(
+        base_plane, base_idx, live, windows, n_pad=db_p.shape[-1],
+        m_pad=q_p.shape[-1])
     mask, dist = sparse_verify_arena_packed_pallas(
-        db_p, q_p, base_p, idx_p, live_p, b=b, S=S, tau=tau,
+        db_p, q_p, base_p, idx_p, live_p, win, b=b, S=S, tau=tau,
         block_m=block_m, block_n=block_n, interpret=_interpret())
     return mask[:m, :n], dist[:m, :n]
 

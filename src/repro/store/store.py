@@ -48,7 +48,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core.hamming import unpack_vertical
-from ..core.segments import Segment, ensure_serial_floor
+from ..core.segments import Segment, ensure_serial_floor, next_serial
 from .atomic import (atomic_write_bytes, atomic_write_dir, atomic_write_json,
                      read_json, sweep_stale_tmp)
 from .wal import (OP_DELETE, OP_INSERT, OP_INSERT_PAYLOAD, WriteAheadLog,
@@ -118,6 +118,9 @@ class CollectionStore:
         # manifest metadata (n_ids / sealed_seq / serial_floor / lineage)
         self._persisted: List[Dict[int, int]] = []
         self._meta: List[Dict[str, object]] = []
+        # per stack: segments recovery stored in another order than
+        # their files hold (``_reserial_reordered``)
+        self._reordered: List[List[Segment]] = []
         self.counters: Dict[str, int] = {
             "checkpoints": 0, "segments_written": 0, "live_rewrites": 0,
             "wal_truncations": 0, "replayed_records": 0,
@@ -135,6 +138,7 @@ class CollectionStore:
         self._stacks = list(index.shards) if self._sharded else [index]
         last = self.wal.next_seq - 1
         self._persisted = [dict() for _ in self._stacks]
+        self._reordered = [[] for _ in self._stacks]
         self._meta = [{"n_ids": None, "sealed_seq": last,
                        "serial_floor": 0, "lineage": []}
                       for _ in self._stacks]
@@ -295,6 +299,7 @@ class CollectionStore:
                            for s, m in enumerate(self._meta)
                            if m["n_ids"]])
             ensure_serial_floor(floor)
+            self._reserial_reordered()
             _base, records, _dropped = read_wal(self.wal.path)
             for seq, op, payload in records:
                 if op == OP_INSERT:
@@ -318,22 +323,41 @@ class CollectionStore:
                 st.maybe_merge()
         return index
 
+    def _reserial_reordered(self) -> None:
+        """Give each segment that ``_load_stack`` reordered a fresh
+        serial, once the serial floor is restored.  The next checkpoint
+        then writes it as a new segment directory and retires the old
+        one after the manifest commits, as a merge would: a later
+        ``live.npy`` rewrite never lands beside rows stored in another
+        order, and a crash in between recovers the old files intact."""
+        for moved in self._reordered:
+            for seg in moved:
+                seg.serial = next_serial()
+        self._reordered = [[] for _ in self._stacks]
+
     def _load_stack(self, i: int, st) -> int:
         sdir = self._stack_dir(i)
         man = read_json(os.path.join(sdir, "MANIFEST.json")) or {
             "n_ids": 0, "sealed_seq": -1, "serial_floor": 0,
             "segments": [], "lineage": []}
         segs: List[Segment] = []
+        self._reordered[i] = []
         for ent in man["segments"]:
             d = os.path.join(sdir, f"seg_{ent['serial']:012d}")
             with np.load(os.path.join(d, "arrays.npz")) as arr:
                 packed, ids = arr["packed"], arr["ids"]
                 pay = arr["payloads"] if "payloads" in arr.files else None
             live = np.load(os.path.join(d, "live.npy"))
-            sk = unpack_vertical(packed, st.b, st.L)
-            segs.append(Segment(index=st._build(sk), packed=packed,
-                                ids=ids, live=live, L=st.L, b=st.b,
-                                serial=int(ent["serial"]), payloads=pay))
+            # rows come back in the order they were sealed in (trie
+            # order on the bst backend), so the rebuild keeps them put
+            segs.append(st._seal(unpack_vertical(packed, st.b, st.L), ids,
+                                 pay, live=live, packed=packed,
+                                 serial=int(ent["serial"])))
+            if not np.array_equal(segs[-1].ids, ids):
+                # stored in another order (id order, before rows were
+                # sealed in trie order): its files no longer match the
+                # rows, so it must not be patched in place
+                self._reordered[i].append(segs[-1])
         st.segments = segs
         st.n_ids = int(man["n_ids"])
         self._persisted[i] = {seg.serial: seg.n - seg.n_live

@@ -54,45 +54,151 @@ def assert_topk_equal(idx, qs, k, tau0=None):
 # kernel exactness
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,m,T,block_n,block_m", [
-    (300, 5, 17, 128, 8),      # pad on both axes
-    (256, 1, 3, 128, 8),       # m=1 degenerate tile, aligned n
-    (130, 9, 200, 128, 4),     # tile-misaligned both ways, T > n block
-    (300, 8, 17, 128, 8),      # the scheduler's buckets at the default
-    (300, 16, 17, 128, 8),     #   query tile, pad lanes
-    (390, 64, 33, 128, 24),    # pad rows: 64 queries in 24-row tiles
-])
-def test_arena_kernel_matches_oracle(n, m, T, block_n, block_m):
+def monotone_lane(rng, n, T, rise=0.7):
+    """An arena base_idx lane as sealed trie-ordered rows give it: rises
+    in steps of 0 or 1, from a random first root, within [0, T)."""
+    steps = rng.random(n) < rise
+    steps[0] = False
+    lane = np.cumsum(steps)
+    lane += rng.integers(0, max(1, T - int(lane[-1])))
+    return np.minimum(lane, T - 1).astype(np.int32)
+
+
+def assert_arena_kernels_match(paths, q, base, idx, live, *, block_n,
+                               block_m, tau=20, S=10):
     """Both arena kernels — full-length vertical columns and packed
     suffix words — against their oracles, bit for bit."""
-    rng = np.random.default_rng(n + m)
-    b, W = 3, 2
-    paths = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, n), np.uint64)
-                        .astype(np.uint32))
-    q = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, m), np.uint64)
-                    .astype(np.uint32))
-    base = jnp.asarray(np.where(rng.random((m, T)) < 0.3, BIG_I,
-                                rng.integers(0, 5, (m, T))).astype(np.int32))
-    idx = jnp.asarray(rng.integers(0, T, n).astype(np.int32))
-    live = jnp.asarray(rng.random(n) < 0.8)
+    b = paths.shape[0]
+    windows = ops.plan_windows(np.asarray(idx), block_n)
     mk, dk = ops.sparse_verify_arena(
-        paths, q, base, idx, live,
-        tau=20, block_n=block_n, block_m=block_m, use_kernel=True)
-    mo, do = ref.sparse_verify_arena_ref(paths, q, base, idx, live, 20)
+        paths, q, base, idx, live, tau=tau, windows=windows,
+        block_n=block_n, block_m=block_m, use_kernel=True)
+    mo, do = ref.sparse_verify_arena_ref(paths, q, base, idx, live, tau)
     np.testing.assert_array_equal(np.asarray(mk),
                                   np.asarray(mo).astype(np.int32))
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(do))
 
-    S = 10                                     # b·S = 30 of 32 bits
     words, q_words = paths[0, 0], q[0, 0]
     mk, dk = ops.sparse_verify_arena_packed(
-        words, q_words, base, idx, live, b=b, S=S, tau=6, block_n=block_n,
-        block_m=block_m, use_kernel=True)
+        words, q_words, base, idx, live, b=b, S=S, tau=tau // 3,
+        windows=windows, block_n=block_n, block_m=block_m, use_kernel=True)
     mo, do = ref.sparse_verify_arena_packed_ref(words, q_words, base, idx,
-                                                live, b, S, 6)
+                                                live, b, S, tau // 3)
     np.testing.assert_array_equal(np.asarray(mk),
                                   np.asarray(mo).astype(np.int32))
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(do))
+
+
+def random_arena(rng, n, m, T, *, b=3, W=2, big_share=0.3, live_share=0.8):
+    paths = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, n), np.uint64)
+                        .astype(np.uint32))
+    q = jnp.asarray(rng.integers(0, 2 ** 32, (b, W, m), np.uint64)
+                    .astype(np.uint32))
+    base = np.where(rng.random((m, T)) < big_share, BIG_I,
+                    rng.integers(0, 5, (m, T))).astype(np.int32)
+    live = rng.random(n) < live_share
+    return paths, q, base, live
+
+
+@pytest.mark.parametrize("n,m,T,block_n,block_m", [
+    (300, 5, 170, 128, 8),     # pad on both axes
+    (256, 1, 300, 128, 8),     # m=1 degenerate tile, aligned n
+    (130, 9, 200, 128, 4),     # tile-misaligned both ways, T > n block
+    (300, 8, 270, 128, 8),     # the scheduler's buckets at the default
+    (300, 16, 270, 128, 8),    #   query tile, pad lanes
+    (390, 64, 330, 128, 24),   # pad rows: 64 queries in 24-row tiles
+])
+def test_arena_kernel_matches_oracle(n, m, T, block_n, block_m):
+    """Both arena kernels — full-length vertical columns and packed
+    suffix words — against their oracles, bit for bit, on a lane that
+    rises in steps of 0 or 1 (sealed rows in trie order)."""
+    rng = np.random.default_rng(n + m)
+    paths, q, base, live = random_arena(rng, n, m, T)
+    idx = monotone_lane(rng, n, T)
+    assert_arena_kernels_match(paths, q, jnp.asarray(base), jnp.asarray(idx),
+                               jnp.asarray(live), block_n=block_n,
+                               block_m=block_m)
+
+
+def segment_lane(rng, sizes, *, delta=0):
+    """The lane a stack of sealed segments (each in trie order, roots
+    laid out in column order) gives, plus ``delta`` columns at the
+    delta's slot after them: (lane, T)."""
+    lanes, root0 = [], 0
+    for size in sizes:
+        lane = monotone_lane(rng, size, size)
+        lane -= lane[0]
+        lanes.append(root0 + lane)
+        root0 += int(lane[-1]) + 1
+    lanes.append(np.full(delta, root0, np.int32))
+    return np.concatenate(lanes).astype(np.int32), root0 + 1
+
+
+@pytest.mark.parametrize("m", [1, 2, 3, 4, 8])
+@pytest.mark.parametrize("case", [
+    "segments",        # windows across segment boundaries, n ragged
+    "delta_slot",      # sealed columns then the delta's slot
+    "all_big",         # every root pruned: the windows read only BIG
+    "dead",            # most lanes tombstoned
+    "flat",            # one root for long runs: windows of one tile
+])
+def test_windowed_verify_matches_oracle(case, m):
+    """The windowed base expansion at the shapes the index gives it:
+    multi-tile blocks (block_n = 512, four 128-lane sub-blocks) over
+    lanes that cross segment boundaries and the delta slot, pruned
+    windows and dead lanes, for every batch bucket up to 8 — bit for
+    bit against the oracles' plain gather."""
+    rng = np.random.default_rng(len(case) * 10 + m)
+    block_n = 512
+    if case == "segments":
+        idx, T = segment_lane(rng, [700, 130, 1001])
+    elif case == "delta_slot":
+        idx, T = segment_lane(rng, [900, 400], delta=333)
+    elif case == "flat":
+        idx = monotone_lane(rng, 1500, 1500, rise=0.002)
+        T = int(idx[-1]) + 1
+    else:
+        idx, T = segment_lane(rng, [1100, 500])
+    paths, q, base, live = random_arena(
+        rng, len(idx), m, T, big_share=1.0 if case == "all_big" else 0.3,
+        live_share=0.1 if case == "dead" else 0.8)
+    assert_arena_kernels_match(paths, q, jnp.asarray(base), jnp.asarray(idx),
+                               jnp.asarray(live), block_n=block_n,
+                               block_m=8, tau=12)
+
+
+@pytest.mark.parametrize("lane", [
+    [0, 2, 3],         # a step of 2
+    [3, 2, 2],         # a step down
+    [-1, 0, 1],        # a negative root
+])
+def test_window_plan_refuses_a_lane_that_breaks_the_invariant(lane):
+    """No silent fallback: a lane that does not rise in steps of 0 or 1
+    has no window plan, and the kernel path has nothing else."""
+    with pytest.raises(ValueError, match="steps of 0 or 1"):
+        ops.plan_windows(np.asarray(lane), 128)
+    b, n, m = 2, 128, 1
+    with pytest.raises(ValueError, match="window plan"):
+        ops.sparse_verify_arena_packed(
+            jnp.zeros((n,), jnp.uint32), jnp.zeros((m,), jnp.uint32),
+            jnp.zeros((m, 4), jnp.int32), jnp.zeros((n,), jnp.int32),
+            jnp.ones((n,), bool), b=b, S=4, tau=1, block_n=n,
+            use_kernel=True)
+
+
+def test_window_plan_starts_and_steps():
+    """The plan's start is each block's first 128-lane tile, its steps
+    the tile rises between sub-blocks, its roots what the blocks span."""
+    lane = np.concatenate([np.zeros(200, np.int64),
+                           np.arange(1, 200 + 1),       # 200 lanes, +1
+                           np.full(112, 200)])          # 512 lanes
+    plan = ops.plan_windows(lane, 256)
+    # blocks: lanes 0-255 (roots 0..56), 256-511 (roots 57..200)
+    np.testing.assert_array_equal(plan.start, [0, 0])
+    # sub-block starts: lane 128 -> root 0 (tile 0); lane 384 -> root
+    # 185 (tile 1), one tile up from lane 256's root 57 (tile 0)
+    np.testing.assert_array_equal(plan.steps, [0, 1])
+    assert plan.roots == 57 + (200 - 57 + 1)
 
 
 def test_arena_kernel_dead_and_pruned_lanes_clamp_to_big():
@@ -100,11 +206,11 @@ def test_arena_kernel_dead_and_pruned_lanes_clamp_to_big():
     paths = jnp.zeros((b, W, n), jnp.uint32)
     q = jnp.zeros((b, W, m), jnp.uint32)
     base = jnp.asarray([[0, BIG_I]] * m, jnp.int32)       # slot 1 pruned
-    idx = jnp.asarray(([0] * 128) + ([1] * 128), jnp.int32)
+    lane = np.asarray(([0] * 128) + ([1] * 128), np.int32)
     live = jnp.asarray(([True] * 64) + ([False] * 192))
-    mask, dist = ops.sparse_verify_arena(paths, q, base, idx, live,
-                                         tau=3, block_n=128,
-                                         use_kernel=True)
+    mask, dist = ops.sparse_verify_arena(
+        paths, q, base, jnp.asarray(lane), live, tau=3,
+        windows=ops.plan_windows(lane, 128), block_n=128, use_kernel=True)
     mask, dist = np.asarray(mask), np.asarray(dist)
     assert mask[:, :64].all()                  # live + reached, dist 0
     assert (dist[:, :64] == 0).all()
@@ -250,7 +356,10 @@ def test_dispatch_spy_one_launch_per_rung_at_16_segments():
     reset_dispatch_stats()
     idx.topk_batch(qs, 5, tau0=idx.L)
     spy = dispatch_stats()
-    assert spy == {"total": 1, "fused": 1, "fanout": 0, "rerank": 0}, spy
+    # 520 sealed columns: every verify runs on the oracle, no windows
+    assert spy == {"total": 1, "fused": 1, "fanout": 0, "rerank": 0,
+                   "windowed_cols": 0, "windows": 0,
+                   "window_roots": 0}, spy
     # multi-rung top-k: exactly one launch per rung
     reset_dispatch_stats()
     res = idx.topk_batch(qs, 5, tau0=0)
@@ -342,7 +451,9 @@ def test_delete_flips_device_liveness_lane_in_place():
     idx.delete(ids[17])                  # no rebuild: same arena arrays
     assert idx._arena is ar
     assert not idx.search(db[17], 0).mask[ids[17]]
-    assert not bool(np.asarray(ar.live)[17])
+    col = int(np.flatnonzero(ar.col_ids == ids[17])[0])   # rows in trie order
+    assert not bool(np.asarray(ar.live)[col])
+    assert int(np.asarray(ar.live).sum()) == 59
 
 
 def test_segment_serials_are_unique_and_survive_merge_away():

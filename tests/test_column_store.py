@@ -268,3 +268,46 @@ def test_suffix_layout_at_least_halves_device_column_bytes():
     store = c._refresh_store()
     assert store.tier_summary()["hot_bytes"] == 0
     assert store.host_bytes() == store.col_bytes() == sfx
+
+
+# -- windowed verify counters ---------------------------------------------
+
+@pytest.mark.parametrize("layout,m", [("suffix", 3), ("suffix", 9),
+                                      ("full", 3)])
+def test_windowed_verify_counters_report_sealed_columns(layout, m):
+    """``dispatch_stats()`` tallies, per fused launch, the columns whose
+    base came through the in-kernel window expansion, the windows (one
+    per column block and query tile) and the roots they span — so the
+    mean roots per window reads off the counters."""
+    from repro.core import bucket_m
+    rng = np.random.default_rng(40)
+    db = rng.integers(0, 4, size=(3000, 12), dtype=np.uint8)
+    idx = SegmentedIndex(12, 2, delta_cap=10 ** 9, auto_merge=False,
+                         layout=layout)
+    ids = idx.insert(db[:2500])
+    idx.flush()
+    idx.insert(db[2500:])                         # 500 delta rows
+    idx.delete(ids[::9])
+    t_root = int(idx.segments[0].index.tail.t_root)
+    qs = db[rng.choice(3000, m)]
+    reset_dispatch_stats()
+    res = idx.topk_batch(qs, 4, tau0=12)          # one rung
+    st = dispatch_stats()
+    # the suffix layout verifies the sealed columns only; the full one
+    # the delta's (bucketed) columns too, at its trailing slot
+    cols = 2500 if layout == "suffix" else 2500 + bucket_m(500)
+    tiles = bucket_m(m) // min(8, bucket_m(m))
+    assert st["fused"] == 1 and st["windowed_cols"] == cols
+    assert st["windows"] == -(-cols // 2048) * tiles
+    extra = 0 if layout == "suffix" else 1        # the delta's slot
+    assert (t_root + extra) * tiles <= st["window_roots"] \
+        <= (t_root + extra + 1) * tiles           # one root per boundary
+    assert 1 <= st["window_roots"] / st["windows"] <= 2048
+    alive = np.ones(3000, bool)
+    alive[ids[::9]] = False
+    d = (db[None, :, :] != qs[:, None, :]).sum(-1)
+    d = np.where(alive[None, :], d, 1 << 20)
+    for r in range(m):
+        want = np.lexsort((np.arange(3000), d[r]))[:4]
+        np.testing.assert_array_equal(np.asarray(res.ids)[r], want)
+        np.testing.assert_array_equal(np.asarray(res.dists)[r], d[r][want])

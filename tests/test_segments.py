@@ -4,6 +4,8 @@ bit-identical top-k (dists AND ids, after the monotone global-id mapping)
 and range results — plus lifecycle mechanics (tombstones, size-tiered
 merges, space accounting) and every backend (bst / multi / sharded)."""
 
+import os
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -269,3 +271,132 @@ def test_stable_ids_survive_merge_and_compact():
     res = idx.topk(db[42], 1)
     assert int(res.ids[0]) == int(ids[42])
     assert int(res.dists[0]) == 0
+
+
+# ---------------------------------------------------------------------------
+# sealed rows in trie order (the windowed verify's invariant)
+# ---------------------------------------------------------------------------
+
+def assert_trie_order(idx):
+    """Every sealed segment stores its rows in its trie's leaf order,
+    ties by id — sketches lexicographically sorted, so each row's ℓ_s
+    root rises in steps of 0 or 1 from 0 — and the column store's group
+    lanes keep rising in steps of 0 or 1 across segments."""
+    for seg in idx.segments:
+        keys = (seg.ids,) + tuple(seg.sketches[:, c]
+                                  for c in range(idx.L - 1, -1, -1))
+        np.testing.assert_array_equal(np.lexsort(keys), np.arange(seg.n))
+        lane = np.asarray(seg.index.tail.leaf_root)[
+            np.asarray(seg.index.id_leaf)]
+        assert lane[0] == 0 and set(np.diff(lane)) <= {0, 1}
+    for group in idx._refresh_store().plan():
+        assert set(np.diff(np.asarray(group.base_idx))) <= {0, 1}
+
+
+def assert_answers_brute(idx, db, alive, qs, k=5, tau=2):
+    """Top-k and range search equal a brute force over the live rows
+    (ties by id)."""
+    d = brute(qs, db)
+    dl = np.where(alive[None, :], d, BIG_I)
+    got = idx.topk_batch(qs, k)
+    for r in range(len(qs)):
+        want = np.lexsort((np.arange(len(db)), dl[r]))[:k]
+        np.testing.assert_array_equal(np.asarray(got.ids)[r], want)
+        np.testing.assert_array_equal(np.asarray(got.dists)[r], dl[r][want])
+    res = idx.search_batch(qs, tau)
+    np.testing.assert_array_equal(res.mask, dl <= tau)
+    np.testing.assert_array_equal(res.dist, np.where(dl <= tau, dl, BIG_I))
+
+
+@pytest.mark.parametrize("stage", ["flush", "merge", "compact", "delete",
+                                   "recover"])
+def test_sealed_rows_in_trie_order_through_lifecycle(tmp_path, stage):
+    """Flush, merge (of non-adjacent segments, whose ids interleave),
+    compact, delete and checkpoint + WAL replay all leave every sealed
+    segment in trie order; a delete by id tombstones exactly the rows
+    of those ids; answers stay equal to the brute force."""
+    from repro.store import CollectionStore
+    rng = np.random.default_rng(31)
+    L = 8
+    db = rng.integers(0, 4, size=(330, L), dtype=np.uint8)
+    db[200:210] = db[5]                   # equal rows across segments
+    db[40:44] = db[300]
+    store = CollectionStore(str(tmp_path / "c"))
+    idx = store.attach(SegmentedIndex(L, _B, delta_cap=100,
+                                      auto_merge=False))
+    ids = np.concatenate([idx.insert(db[lo:lo + 100])
+                          for lo in range(0, 330, 100)])
+    assert len(idx.segments) == 3         # + 30 delta rows
+    alive = np.ones(len(db), bool)
+    if stage != "flush":
+        idx.merge(2, 0)                   # ids 200-299, then 0-99
+    if stage in ("compact", "delete", "recover"):
+        gone = rng.choice(len(db), 40, replace=False)
+        assert idx.delete(ids[gone]) == 40
+        alive[gone] = False
+        for seg in idx.segments:          # the rows of those ids, no other
+            np.testing.assert_array_equal(seg.live, alive[seg.ids])
+    if stage == "compact":
+        assert idx.compact() == len(idx.segments)
+        assert all(seg.live.all() for seg in idx.segments)
+    if stage == "recover":
+        store.wal.sync()
+        idx = CollectionStore(str(tmp_path / "c")).recover(
+            SegmentedIndex(L, _B, delta_cap=100, auto_merge=False))
+        for seg in idx.segments:
+            np.testing.assert_array_equal(seg.live, alive[seg.ids])
+    assert_trie_order(idx)
+    assert_answers_brute(idx, db, alive, db[[5, 40, 123, 300, 329]])
+
+
+def test_recover_rewrites_an_id_ordered_checkpoint_whole(tmp_path):
+    """Segments checkpointed with their rows in id order (as stores wrote
+    them before rows were sealed in trie order) recover in trie order
+    under a fresh serial, so the next checkpoint writes them whole
+    instead of patching ``live.npy`` beside id-ordered arrays: deletes
+    made after a recovery survive the next one, and no deleted row
+    comes back."""
+    from repro.store import CollectionStore
+    rng = np.random.default_rng(7)
+    L = 8
+    db = rng.integers(0, 4, size=(200, L), dtype=np.uint8)
+    root = str(tmp_path / "c")
+
+    def fresh():
+        return SegmentedIndex(L, _B, delta_cap=100, auto_merge=False)
+
+    store = CollectionStore(root)
+    idx = store.attach(fresh())
+    ids = np.concatenate([idx.insert(db[lo:lo + 100]) for lo in (0, 100)])
+    alive = np.ones(len(db), bool)
+    gone = rng.choice(len(db), 30, replace=False)
+    idx.delete(ids[gone])
+    alive[gone] = False
+    store.checkpoint(0)
+    for seg in idx.segments:
+        d = os.path.join(root, f"seg_{seg.serial:012d}")
+        with np.load(os.path.join(d, "arrays.npz")) as arr:
+            arrays = {k: arr[k] for k in arr.files}
+        by_id = np.argsort(arrays["ids"])
+        assert not np.array_equal(by_id, np.arange(len(by_id)))
+        np.savez(os.path.join(d, "arrays.npz"),
+                 **{k: v[by_id] for k, v in arrays.items()})
+        np.save(os.path.join(d, "live.npy"),
+                np.load(os.path.join(d, "live.npy"))[by_id])
+    store.close()
+    for _ in range(2):
+        store = CollectionStore(root)
+        idx = store.recover(fresh())
+        for seg in idx.segments:
+            np.testing.assert_array_equal(seg.live, alive[seg.ids])
+        gone = rng.choice(np.flatnonzero(alive), 10, replace=False)
+        assert idx.delete(ids[gone]) == 10
+        alive[gone] = False
+        store.checkpoint(0)
+        store.close()
+    store = CollectionStore(root)
+    idx = store.recover(fresh())
+    for seg in idx.segments:
+        np.testing.assert_array_equal(seg.live, alive[seg.ids])
+    assert_trie_order(idx)
+    assert_answers_brute(idx, db, alive, db[[3, 77, 150, 199]])

@@ -2,10 +2,11 @@
 
 Each case lowers one ``*_pallas`` entry point with ``interpret=False``
 against a described (not attached) ``v5e:2x2`` topology and compiles it
-with the TPU compiler, at n = 2^20 columns and m ∈ {1, 8, 64} queries:
-what Mosaic refuses (lane blocks that are not a multiple of 128 nor the
-whole axis, lane gathers, blocks past the scoped VMEM limit) fails here
-instead of on the chip.  Nothing runs, so results are covered by the
+with the TPU compiler, at n = 2^20 columns and m ∈ {1, 8, 64} queries,
+and the windowed arena verify also at the review corpus's two sealed
+segments: what Mosaic refuses (lane blocks that are not a multiple of
+128 nor the whole axis, lane gathers beyond one vreg, blocks past the
+scoped VMEM limit) fails here instead of on the chip.  Nothing runs, so results are covered by the
 interpret-mode tests, not by this file.
 
 The topology is described inside a module-scoped fixture, never at
@@ -17,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.hamming_kernel import (DEFAULT_BLOCK_M,
+from repro.kernels.hamming_kernel import (DEFAULT_BLOCK_M, DEFAULT_BLOCK_N,
                                           exact_rerank_pallas,
                                           hamming_distances_pallas,
                                           sparse_verify_arena_packed_pallas,
@@ -26,6 +27,7 @@ from repro.kernels.hamming_kernel import (DEFAULT_BLOCK_M,
 
 N = 1 << 20
 T_ROOTS = 4097          # ℓ_s roots of a few segments + the delta slot
+REVIEW_T = 9_252_432    # ℓ_s roots of the review corpus's two segments
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +58,13 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _windows(sharding, n):
+    """(start, steps) specs of one window plan over n columns."""
+    nb = n // DEFAULT_BLOCK_N
+    return (_spec(sharding, (nb,), jnp.int32),
+            _spec(sharding, (nb,), jnp.int32))
+
+
 def _compile_checked(fn, args, kwargs):
     compiled = fn.lower(*args, interpret=False, **kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -68,9 +77,23 @@ def test_arena_packed_verify_compiles_review(one_chip, m):
     u32, i32 = jnp.uint32, jnp.int32
     args = (_spec(one_chip, (N,), u32), _spec(one_chip, (m,), u32),
             _spec(one_chip, (m, T_ROOTS), i32), _spec(one_chip, (N,), i32),
-            _spec(one_chip, (N,), i32))
+            _spec(one_chip, (N,), i32), _windows(one_chip, N))
     _compile_checked(sparse_verify_arena_packed_pallas, args,
                      dict(b=b, S=S, tau=2, block_m=min(DEFAULT_BLOCK_M, m)))
+
+
+@pytest.mark.parametrize("m", [1, 2, 4])
+@pytest.mark.parametrize("n,S", [(8_388_608, 4), (4_194_304, 5)])
+def test_windowed_verify_compiles_review_segments(one_chip, n, S, m):
+    """The windowed packed verify at the review corpus's sealed
+    segments (ℓ_s = 12 and 11 of L = 16) over its whole root plane, at
+    the scheduler's batch buckets up to ``max_batch`` 4."""
+    u32, i32 = jnp.uint32, jnp.int32
+    args = (_spec(one_chip, (n,), u32), _spec(one_chip, (m,), u32),
+            _spec(one_chip, (m, REVIEW_T), i32), _spec(one_chip, (n,), i32),
+            _spec(one_chip, (n,), i32), _windows(one_chip, n))
+    _compile_checked(sparse_verify_arena_packed_pallas, args,
+                     dict(b=2, S=S, tau=3, block_m=m))
 
 
 @pytest.mark.parametrize("m", [1, 8, 64])
@@ -81,7 +104,7 @@ def test_arena_verify_compiles_gist(one_chip, m):
             _spec(one_chip, (b, W, m), jnp.uint32),
             _spec(one_chip, (m, T_ROOTS), jnp.int32),
             _spec(one_chip, (N,), jnp.int32),
-            _spec(one_chip, (N,), jnp.int32))
+            _spec(one_chip, (N,), jnp.int32), _windows(one_chip, N))
     _compile_checked(sparse_verify_arena_pallas, args,
                      dict(tau=9, block_m=min(DEFAULT_BLOCK_M, m)))
 
