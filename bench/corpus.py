@@ -13,12 +13,17 @@ by two maps that keep every shape the index builds:
   of segments) holds the same set of rows up to those relabelings.
 
 So every seed builds tries, column stores and programs of the same
-shapes, the persistent compile cache serves every seed after the first,
-and the seed changes the rows, the ids that answer and the queries.
+shapes, and the persistent compile cache serves every seed after the
+first.  The queries are one fixed set drawn against the base draw and
+carried through the same two maps, so each query finds the same
+distances, candidates and τ-ladder rungs under every seed: the seed
+changes the labels, the ids that answer and the order in which the
+queries are sent, never the amount of work.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import numpy as np
@@ -33,39 +38,63 @@ def _rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed) & ((1 << 64) - 1), stream])
 
 
+@functools.lru_cache(maxsize=2)
+def _relabeling(n: int, L: int, b: int, delta_cap: int, seed: int):
+    """The seed's two maps of the base draw: ``perms[j]`` relabels
+    position j's alphabet, and ``shuffles[i]`` reorders the rows of the
+    i-th block of ``delta_cap`` rows (module docstring)."""
+    rng = _rng(seed, 0)
+    perms = np.stack([rng.permutation(1 << b).astype(np.uint8)
+                      for _ in range(L)])
+    shuffles = tuple(rng.permutation(min(n, lo + delta_cap) - lo)
+                     for lo in range(0, n, delta_cap))
+    return perms, shuffles
+
+
 def make_corpus(n: int, L: int, b: int, delta_cap: int,
                 seed: int) -> np.ndarray:
     """(n, L) uint8 corpus over [0, 2^b) for ``seed``."""
     base = np.random.default_rng(BASE_SEED).integers(
         0, 1 << b, size=(n, L), dtype=np.uint8)
-    rng = _rng(seed, 0)
+    perms, shuffles = _relabeling(n, L, b, delta_cap, seed)
     db = np.empty_like(base)
     for j in range(L):
-        perm = rng.permutation(1 << b).astype(np.uint8)
-        db[:, j] = perm[base[:, j]]
+        db[:, j] = perms[j][base[:, j]]
     del base
-    for lo in range(0, n, delta_cap):
-        hi = min(n, lo + delta_cap)
-        db[lo:hi] = db[lo:hi][rng.permutation(hi - lo)]
+    for lo, shuffle in zip(range(0, n, delta_cap), shuffles):
+        db[lo:lo + len(shuffle)] = db[lo:lo + len(shuffle)][shuffle]
     return db
 
 
 def make_queries(db: np.ndarray, b: int, count: int, perturbed_share: float,
-                 flips: int, seed: int, stream: int = 1) -> np.ndarray:
-    """(count, L) uint8 queries: ``round(count * perturbed_share)``
-    corpus rows with ``flips`` positions redrawn (near neighbours exist),
-    the rest uniform random rows, shuffled together."""
+                 flips: int, seed: int, stream: int = 1, *,
+                 delta_cap: int) -> np.ndarray:
+    """(count, L) uint8 queries for the corpus ``make_corpus`` made from
+    ``seed`` and ``delta_cap``: ``round(count * perturbed_share)`` corpus
+    rows with ``flips`` positions redrawn (near neighbours exist), the
+    rest uniform random rows.  The rows, positions and characters are
+    drawn once for ``stream`` against the base draw, and the seed's maps
+    carry them over (module docstring); the seed then shuffles them."""
     n, L = db.shape
-    rng = _rng(seed, stream)
+    perms, shuffles = _relabeling(n, L, b, delta_cap, seed)
+    rng = _rng(BASE_SEED, stream)       # the same set for every seed
     n_pert = int(round(count * perturbed_share))
+    src = rng.integers(0, n, n_pert)    # base rows
+    at = np.empty(n_pert, np.int64)     # where the seed's corpus holds them
+    for lo, shuffle in zip(range(0, n, delta_cap), shuffles):
+        here = (src >= lo) & (src < lo + len(shuffle))
+        if here.any():
+            inv = np.empty_like(shuffle)
+            inv[shuffle] = np.arange(len(shuffle))
+            at[here] = lo + inv[src[here] - lo]
     q = np.empty((count, L), np.uint8)
-    q[:n_pert] = db[rng.integers(0, n, n_pert)]
+    q[:n_pert] = db[at]
     for i in range(n_pert):
         pos = rng.integers(0, L, size=flips)
-        q[i, pos] = rng.integers(0, 1 << b, size=flips)
-    q[n_pert:] = rng.integers(0, 1 << b, size=(count - n_pert, L),
-                              dtype=np.uint8)
-    return q[rng.permutation(count)]
+        q[i, pos] = perms[pos, rng.integers(0, 1 << b, size=flips)]
+    rest = rng.integers(0, 1 << b, size=(count - n_pert, L))
+    q[n_pert:] = perms[np.arange(L), rest]
+    return q[_rng(seed, stream).permutation(count)]
 
 
 def arrival_offsets(count: int, seconds: float, order: int) -> np.ndarray:

@@ -1,10 +1,11 @@
 """One top-k cell on the served path: set-up, open-loop window, check.
 
 Set-up (timed as ``setup_s``, from process start to the first request
-of the window): the corpus from the seed, a ``Scheduler`` with one
-collection, the corpus loaded through ``Scheduler.submit_insert`` in
-chunks (seals and merges as the configuration states), and a warm-up of
-the cell's own shape buckets with queries of the cell's own mix.
+of the window, and held to the cell's ``setup_limit_s``): the corpus
+from the seed, a ``Scheduler`` with one collection, the corpus loaded
+through ``Scheduler.submit_insert`` in chunks (seals and merges as the
+configuration states), and a warm-up of the cell's own shape buckets
+with queries of the cell's own mix.
 
 Window: the scheduler runs threaded; the generator sends each request
 at its scheduled time whether or not earlier ones have been answered
@@ -31,6 +32,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from . import corpus, roofline, spec, xplane
+from .setup_limit import SetupLimit
 
 SYNC_MARK = "bench.clock_sync"
 LATE_WAIT_S = 60.0
@@ -151,12 +153,15 @@ def served_collection(cfg: dict, trace: bool):
 
 
 class TopkCell:
-    """The served top-k path of one cell, set up from a seed."""
+    """The served top-k path of one cell, set up from a seed; each set-up
+    stage is named to ``limit``."""
 
-    def __init__(self, cell: spec.Cell, seed: int, *, trace: bool = False):
+    def __init__(self, cell: spec.Cell, seed: int, limit: SetupLimit, *,
+                 trace: bool = False):
         import jax
 
         self.cell, self.seed, self.traced = cell, seed, trace
+        self.limit = limit
         cfg, traffic = cell.config, cell.traffic
         self.n, self.L, self.b = int(cfg["n"]), int(cfg["L"]), int(cfg["b"])
         self.k = int(traffic["k"])
@@ -164,6 +169,7 @@ class TopkCell:
         self.compile_s = compile_events()
 
         t0 = time.perf_counter()
+        limit.stage("corpus")
         self.db = corpus.make_corpus(self.n, self.L, self.b,
                                      int(cfg["delta_cap"]), seed)
         log(f"corpus: {cfg['name']} n={self.n} L={self.L} b={self.b} "
@@ -171,6 +177,9 @@ class TopkCell:
         self.max_batch = int(cfg["serving"]["max_batch"])
         self.sched, self.index = served_collection(cfg, trace)
         t0 = time.perf_counter()
+        counters = self.index.counters
+        limit.stage("load", lambda: f"{counters['flushes']} seals and "
+                    f"{counters['merges']} merges done")
         futs = [self.sched.submit_insert("c", self.db[lo:hi])
                 for lo, hi in corpus.split_blocks(self.n,
                                                   int(cfg["insert_chunk"]))]
@@ -195,9 +204,10 @@ class TopkCell:
 
     def queries(self, count: int, stream: int = 1) -> np.ndarray:
         t = self.cell.traffic
-        return corpus.make_queries(self.db, self.b, count,
-                                   float(t["perturbed_share"]),
-                                   int(t["flips"]), self.seed, stream)
+        return corpus.make_queries(
+            self.db, self.b, count, float(t["perturbed_share"]),
+            int(t["flips"]), self.seed, stream,
+            delta_cap=int(self.cell.config["delta_cap"]))
 
     def warm_up(self) -> None:
         """Every shape bucket the window can dispatch, one batch of the
@@ -210,7 +220,12 @@ class TopkCell:
         tr0 = searcher_cache_info()["traces"]
         seen = []
         for bi, bucket in enumerate(bucket_table(self.max_batch)):
-            d0 = dispatch_stats()["fused"]
+            d0, cb = dispatch_stats()["fused"], len(self.compile_s)
+            self.limit.stage(
+                f"warm-up bucket {bucket}",
+                lambda d0=d0, cb=cb: f"{dispatch_stats()['fused'] - d0} "
+                f"rung dispatches begun, {len(self.compile_s) - cb} "
+                f"compiles done ({sum(self.compile_s[cb:]):.3f} s)")
             futs = [self.sched.submit_topk("c", q, self.k) for q in
                     self.queries(bucket, stream=1000 + 16 * bi)]
             self.sched.pump()
@@ -409,19 +424,17 @@ def compare(db: np.ndarray, b: int, qs: np.ndarray, k: int, answers: list,
 
 
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
-        t_start: float, *, control: bool = False,
+        limit: SetupLimit, *, control: bool = False,
         peaks: Optional[dict] = None) -> dict:
     """One run of the cell, as ``run.py`` reports it."""
-    tc = TopkCell(cell, seed, trace=trace)
+    tc = TopkCell(cell, seed, limit, trace=trace)
     rate = float(cell.settings["rate_per_s"])
     count = max(1, int(round(rate * seconds)))
     qs = tc.queries(count)
     offsets = corpus.arrivals(cell.traffic, count, seconds)
     tc.warm_up()
-    setup = {}
     win = tc.window(qs, offsets, seconds, peaks=peaks,
-                    before_first=lambda: setup.setdefault(
-                        "s", time.perf_counter() - t_start))
+                    before_first=limit.disarm)
     describe_window(win, seconds, rate)
     peak = tc.peak_bytes()
     db, b, k = tc.db, tc.b, tc.k
@@ -431,5 +444,5 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     return {"attempted": count, "failed": count - win.answered,
             "e2e": {"topk_mean_ms": float(lat.mean()),
                     "topk_p95_ms": float(np.percentile(lat, 95)),
-                    "setup_s": setup["s"]},
+                    "setup_s": limit.setup_s},
             "peak_bytes": peak, "window": win, "checks": checks}

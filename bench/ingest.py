@@ -1,7 +1,7 @@
 """One bulk-ingest cell on the served path: set-up, window, check.
 
-Set-up (``setup_s``): the corpus from the seed and a ``Scheduler`` with
-one empty collection.
+Set-up (``setup_s``, held to the cell's ``setup_limit_s``): the corpus
+from the seed and a ``Scheduler`` with one empty collection.
 
 Window: the client loads the first ``rows`` rows of the corpus through
 ``Scheduler.submit_insert`` in chunks of ``chunk_rows``, keeping
@@ -30,10 +30,11 @@ import numpy as np
 from . import corpus, spec
 from .drive import (LATE_WAIT_S, SYNC_MARK, Window, compare, compile_events,
                     log, read_trace_into, served_collection)
+from .setup_limit import SetupLimit
 
 
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
-        t_start: float, *, control: bool = False,
+        limit: SetupLimit, *, control: bool = False,
         peaks: Optional[dict] = None) -> dict:
     import jax
 
@@ -46,10 +47,12 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     compile_s = compile_events()
 
     t0 = time.perf_counter()
+    limit.stage("corpus")
     db = corpus.make_corpus(n, L, b, delta_cap, seed)
     log(f"corpus: {cfg['name']} n={n} L={L} b={b} seed={seed} in "
         f"{time.perf_counter() - t0:.3f} s; the window loads {rows} rows "
         f"in chunks of {chunk}, {in_flight} in flight")
+    limit.stage("collection")
     sched, index = served_collection(cfg, trace)
     events: list = []
     tap = index.event_hook
@@ -85,7 +88,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         with jax.profiler.TraceAnnotation(SYNC_MARK):
             pass
     t_win = time.perf_counter()
-    setup_s = t_win - t_start
+    setup_s = limit.disarm(t_win)
     t_close = t_win + seconds
     outstanding: collections.deque = collections.deque()
     sent = 0

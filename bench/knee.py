@@ -40,7 +40,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import corpus, drive, run, spec  # noqa: E402
+from bench import corpus, drive, run, setup_limit, spec  # noqa: E402
 
 # last quarter's median latency over the first quarter's, above which
 # the backlog counts as growing
@@ -60,9 +60,10 @@ def main(argv=None) -> int:
         print("knee: JAX found no TPU", file=sys.stderr)
         return 3
     run.use_compile_cache()
-    tc = drive.TopkCell(cell, args.seed)
+    limit = setup_limit.SetupLimit(cell.name, cell.setup_limit_s, T_START)
+    tc = drive.TopkCell(cell, args.seed, limit)
     tc.warm_up()
-    drive.log(f"set-up: {time.perf_counter() - T_START:.3f} s")
+    drive.log(f"set-up: {limit.disarm():.3f} s")
     rates = [float(r) for r in args.rates.split(",")]
     knee = 0                            # rates[:knee] all keep up
     for j, rate in enumerate(rates):

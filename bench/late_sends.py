@@ -21,6 +21,7 @@ import collections
 import json
 import pathlib
 import sys
+import time
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path[:] = [p for p in sys.path
@@ -59,6 +60,7 @@ def innermost(spans, t: float) -> str:
 
 
 def main(argv=None) -> int:
+    t_start = time.perf_counter()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -66,17 +68,18 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    from bench import corpus, drive, run, spec
+    from bench import corpus, drive, run, setup_limit, spec
     cell = spec.load_cell(args.workload, ROOT)
     run.use_compile_cache()
-    tc = drive.TopkCell(cell, args.seed, trace=True)
+    limit = setup_limit.SetupLimit(cell.name, cell.setup_limit_s, t_start)
+    tc = drive.TopkCell(cell, args.seed, limit, trace=True)
     tc.traced = False                  # spans in the ring, no profiler
     rate = float(cell.settings["rate_per_s"])
     count = max(1, int(round(rate * args.seconds)))
     qs = tc.queries(count)
     offsets = corpus.arrivals(cell.traffic, count, args.seconds)
     tc.warm_up()
-    win = tc.window(qs, offsets, args.seconds)
+    win = tc.window(qs, offsets, args.seconds, before_first=limit.disarm)
     drive.describe_window(win, args.seconds, rate)
     spans = worker_spans(tc.sched.tracer)
     scheduled = win.t_win + offsets

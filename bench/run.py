@@ -15,6 +15,13 @@ limit); the last lines of standard error repeat the checks.
 ``--control 1`` puts the reference, one step down in precision, in the
 program's place for the output check (``reference.py``): such a run
 has to come out not correct.
+
+Exit codes: 0 a result line printed (correct or not); 2 the cell, its
+files or the program not found; 3 no TPU, or fewer chips than the cell
+asks for; 4 set-up passed the cell's ``setup_limit_s`` (from process
+start to the first request, what ``setup_s`` measures): the run ends
+there with one line on standard error and no result
+(``setup_limit.py``).
 """
 
 from __future__ import annotations
@@ -37,7 +44,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import spec  # noqa: E402
+from bench import setup_limit, spec  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 
@@ -68,13 +75,25 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_chip: bool = True,
          t_start: float = T_START) -> int:
     """The command; the keywords let a test drive a run without the
     chip (``require_chip=False`` with ``peaks`` given for a traced run)
-    and without touching the persistent compile cache."""
+    and without touching the persistent compile cache.  ``setup_s`` and
+    the set-up limit count from ``t_start``: process start for the
+    command, the call for a run inside a longer process."""
     args = _parse(argv)
     try:
         cell = spec.load_cell(args.workload, root)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, ValueError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
+    limit = setup_limit.SetupLimit(cell.name, cell.setup_limit_s, t_start)
+    try:
+        return _run(args, cell, limit, root=root, require_chip=require_chip,
+                    compile_cache=compile_cache, peaks=peaks)
+    finally:
+        limit.disarm()
+
+
+def _run(args, cell, limit, *, root, require_chip, compile_cache,
+         peaks) -> int:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         import jax
@@ -98,7 +117,7 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_chip: bool = True,
     from bench import drive, ingest
     kinds = {"topk": drive.run, "ingest": ingest.run}
     out = kinds[cell.traffic["kind"]](
-        cell, args.seed, args.seconds, bool(args.trace), t_start,
+        cell, args.seed, args.seconds, bool(args.trace), limit,
         control=bool(args.control), peaks=peaks)
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,

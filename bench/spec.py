@@ -2,7 +2,8 @@
 
 * ``BENCHMARK.json`` (checkout root): the cells, the configurations
   (each names its file), the end-to-end and per-layer metrics;
-* ``bench/workloads/<cell>.json``: the cell's own settings (its rate);
+* ``bench/workloads/<cell>.json``: the cell's own settings: its
+  set-up limit ``setup_limit_s``, which every cell has, and any rate;
 * ``bench/traffic/<traffic>.json``: the parameters of a traffic mix;
 * ``bench/metrics/<metric>.py``: the reader of one per-layer metric.
 
@@ -29,6 +30,8 @@ class Cell:
     config: dict              # the configuration file's contents
     traffic: dict             # the traffic mix file's contents
     settings: dict            # the cell's own file
+    setup_limit_s: float      # seconds from process start to the first
+    #                           request, at most (``setup_limit.py``)
     end_to_end: List[dict]    # end-to-end metric entries this cell reports
     per_layer: List[dict]     # per-layer metric entries this cell reports
 
@@ -54,13 +57,18 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     config = json.loads((root / cfg_entry["file"]).read_text())
     traffic = json.loads(
         (bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
-    settings = json.loads(
-        (bench_dir / "workloads" / f"{name}.json").read_text())
+    path = bench_dir / "workloads" / f"{name}.json"
+    settings = json.loads(path.read_text())
+    limit = settings.get("setup_limit_s")
+    if (isinstance(limit, bool) or not isinstance(limit, (int, float))
+            or not limit > 0):
+        raise ValueError(f"{path} needs a positive setup_limit_s, not "
+                         f"{limit!r}")
     e2e = [m for m in bench["end_to_end"] if _reports(m, name)]
     layer = [m for m in bench["per_layer"] if _reports(m, name)]
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic, settings=settings, end_to_end=e2e,
-                per_layer=layer)
+                traffic=traffic, settings=settings,
+                setup_limit_s=float(limit), end_to_end=e2e, per_layer=layer)
 
 
 def metric_reader(name: str,

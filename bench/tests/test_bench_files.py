@@ -8,6 +8,7 @@ import pathlib
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -34,6 +35,9 @@ def test_every_file_named_in_benchmark_json_is_found():
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"], ROOT)
         assert cell.config["name"] == w["config"]
+        limit = json.loads(named[f"workload:{w['name']}"].read_text())[
+            "setup_limit_s"]
+        assert limit > 0 and cell.setup_limit_s == limit
         assert cell.end_to_end and cell.per_layer
         assert cell.traffic["kind"] in ("topk", "ingest")
 
@@ -56,7 +60,7 @@ def test_new_cell_config_traffic_and_metric_are_files_only(tmp_path):
         "kind": "topk", "k": 3, "arrivals": "poisson", "arrival_order": 5,
         "perturbed_share": 0.25, "flips": 1}))
     (b / "workloads" / "tiny.topk3.sparse.json").write_text(
-        json.dumps({"rate_per_s": 3.0}))
+        json.dumps({"rate_per_s": 3.0, "setup_limit_s": 120}))
     (b / "metrics" / "answered_share.py").write_text(
         "def read(win):\n    return 100.0 * win.answered / win.count\n")
     bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
@@ -77,6 +81,7 @@ def test_new_cell_config_traffic_and_metric_are_files_only(tmp_path):
     assert {k: v for k, v in after.items() if k in before} == before
     cell = spec.load_cell("tiny.topk3.sparse", tmp_path)
     assert cell.config["n"] == 4096 and cell.traffic["k"] == 3
+    assert cell.setup_limit_s == 120
     assert [m["name"] for m in cell.per_layer][-1] == "answered_share"
     win = Window(count=4, answered=3, latency_s=np.zeros(4), answers=[],
                  errors=[], partial=0, late_s=np.zeros(4), t_win=0.0, t_last=1.0,
@@ -96,14 +101,38 @@ def test_new_cell_config_traffic_and_metric_are_files_only(tmp_path):
                                np.sort(np.diff(np.append(ours, 10.0))))
 
 
+@pytest.mark.parametrize("limit", ["missing", 0, -60, "360", True, None])
+def test_a_cell_without_a_positive_setup_limit_is_refused(tmp_path, capsys,
+                                                          limit):
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    cell = "review.topk10.poisson"
+    path = tmp_path / "bench" / "workloads" / f"{cell}.json"
+    settings = json.loads(path.read_text())
+    if limit == "missing":
+        del settings["setup_limit_s"]
+    else:
+        settings["setup_limit_s"] = limit
+    path.write_text(json.dumps(settings))
+    with pytest.raises(ValueError, match="setup_limit_s"):
+        spec.load_cell(cell, tmp_path)
+    from bench import run
+    assert run.main(["--workload", cell, "--seed", "1", "--seconds", "1"],
+                    root=tmp_path, compile_cache=False) == 2
+    out, err = capsys.readouterr()
+    assert out == "" and "positive setup_limit_s" in err
+
+
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 12345, 2**40 + 3])
 def test_generator_is_fixed_by_seed(seed):
     db = corpus.make_corpus(3000, 16, 2, 1024, seed)
     assert np.array_equal(db, corpus.make_corpus(3000, 16, 2, 1024, seed))
     other = corpus.make_corpus(3000, 16, 2, 1024, seed + 1)
     assert not np.array_equal(db, other)
-    q = corpus.make_queries(db, 2, 50, 0.5, 2, seed)
-    assert np.array_equal(q, corpus.make_queries(db, 2, 50, 0.5, 2, seed))
+    q = corpus.make_queries(db, 2, 50, 0.5, 2, seed, delta_cap=1024)
+    assert np.array_equal(
+        q, corpus.make_queries(db, 2, 50, 0.5, 2, seed, delta_cap=1024))
     # every seed sends at the same times
     a = corpus.arrival_offsets(240, 30.0, 2019)
     assert np.array_equal(a, corpus.arrival_offsets(240, 30.0, 2019))
@@ -126,9 +155,29 @@ def test_every_seed_builds_the_same_trie_shapes():
     assert shapes[0] == shapes[1] == shapes[2]
 
 
+@pytest.mark.parametrize("stream", [1, 1000])
+def test_every_seed_draws_the_same_work(stream):
+    """The queries are one set carried by each seed's relabeling: every
+    query's distances to the corpus are the same under every seed, only
+    the labels, ids and send order differ."""
+    from bench.reference import numpy_dists, pack_rows
+    profiles, orders = [], []
+    for seed in (1, 2, 2**35):
+        db = corpus.make_corpus(5000, 16, 2, 2048, seed)
+        q = corpus.make_queries(db, 2, 40, 0.5, 2, seed, stream,
+                                delta_cap=2048)
+        packed = pack_rows(db, 2)
+        hist = [tuple(np.bincount(numpy_dists(packed, x, 16, 2),
+                                  minlength=17)) for x in q]
+        profiles.append(sorted(hist))
+        orders.append(hist)
+    assert profiles[0] == profiles[1] == profiles[2]
+    assert orders[0] != orders[1]
+
+
 def test_query_mix_has_near_neighbours():
     db = corpus.make_corpus(2000, 16, 2, 1024, 3)
-    q = corpus.make_queries(db, 2, 40, 0.5, 2, 3)
+    q = corpus.make_queries(db, 2, 40, 0.5, 2, 3, delta_cap=1024)
     from bench.reference import numpy_dists, pack_rows
     packed = pack_rows(db, 2)
     nearest = [int(numpy_dists(packed, x, 16, 2).min()) for x in q]
@@ -178,6 +227,27 @@ def test_trace_reduction_on_recorded_trace():
                         (579183 + 262340) / 1e9]]
 
 
+def test_an_empty_device_plane_leaves_the_host_ops(monkeypatch):
+    """The recorded CPU trace with an empty ``/device:CUSTOM:...`` plane
+    beside it, as a process that has loaded the TPU library writes one,
+    reads the same ops: a device plane is one with an "XLA Ops" line."""
+    from jax.profiler import ProfileData
+    path = str(DATA / "cpu_trace.xplane.pb")
+    plain = xplane.read_trace(path)
+    assert plain.ops
+    real = ProfileData.from_file
+
+    class Plane:
+        name, lines = "/device:CUSTOM:Megascale Trace", []
+
+    class WithEmptyPlane:
+        def __init__(self, pd):
+            self.planes = [Plane()] + list(pd.planes)
+    monkeypatch.setattr(ProfileData, "from_file",
+                        lambda p: WithEmptyPlane(real(p)))
+    assert xplane.read_trace(path) == plain
+
+
 def test_union_of_overlapping_intervals():
     iv = [(0, 10), (5, 20), (30, 40), (35, 36), (50, 50)]
     assert xplane.union(iv) == [(0, 20), (30, 40)]
@@ -201,9 +271,12 @@ def test_self_time_of_nested_ops_and_short_names():
 
 
 def test_run_refuses_without_a_chip(capsys):
+    """In this process: its set-up timed from this call, so that the
+    cell's set-up limit does not count the test session before it."""
     from bench import run
     rc = run.main(["--workload", "review.topk10.poisson", "--seed", "1",
-                   "--seconds", "1"], compile_cache=False)
+                   "--seconds", "1"], compile_cache=False,
+                  t_start=time.perf_counter())
     assert rc != 0
     assert capsys.readouterr().out == ""
 

@@ -11,9 +11,11 @@ that the arithmetic is tested apart from the file format:
 
 On a TPU the operations are the events of each ``/device:TPU:n`` plane's
 "XLA Ops" line, and the programs those of its "XLA Modules" line.  A CPU
-trace has no device plane: its operations are the host events that carry
+trace has no such plane: its operations are the host events that carry
 an ``hlo_op`` stat, which is enough to test the code and never gives a
-device metric.
+device metric.  A device plane is one with an "XLA Ops" line: a process
+that has loaded the TPU library without running on it (a compile for a
+described chip) also writes an empty ``/device:CUSTOM:...`` plane.
 """
 
 from __future__ import annotations
@@ -56,7 +58,8 @@ def read_trace(path: str, annotation_prefix: str = "bench.") -> Trace:
     ops: Dict[str, list] = defaultdict(list)
     modules: Dict[str, list] = defaultdict(list)
     annotations: list = []
-    device_planes = [p for p in pd.planes if p.name.startswith("/device:")]
+    device_planes = [p for p in pd.planes if p.name.startswith("/device:")
+                     and any(line.name == "XLA Ops" for line in p.lines)]
     for plane in device_planes:
         for line in plane.lines:
             if line.name == "XLA Ops":
